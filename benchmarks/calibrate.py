@@ -34,6 +34,7 @@ import sys
 import jax
 
 from repro import api
+from repro.compile_cache import enable_compile_cache
 from repro.core.graph import DEFAULT_SPARSE_THRESHOLD, random_instance
 from repro.core.solver import solve_device
 
@@ -99,6 +100,7 @@ def run(csv=None, json_path: str | None = None) -> int | None:
 
 
 def main(argv=None) -> None:
+    enable_compile_cache()
     argv = list(sys.argv[1:] if argv is None else argv)
     json_path = None
     if "--json" in argv:

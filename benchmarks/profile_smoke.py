@@ -12,6 +12,9 @@ because the work changed or because the machine did.
 
 Message-passing numbers are loop-corrected to ``mp_iters`` (XLA counts a
 scan body once; see :func:`repro.roofline.solver.loop_corrected`).
+Roofline fields are filled only on a TPU, from the peaks of its
+``device_kind`` (:data:`repro.roofline.analysis.PEAKS`); elsewhere they are
+null, "not measured".
 """
 from __future__ import annotations
 
@@ -21,6 +24,7 @@ import platform
 
 import jax
 
+from repro.roofline.analysis import hardware
 from repro.roofline.solver import profile_solve_round
 
 from benchmarks.solver_smoke import GRAPH_IMPLS, SMOKE_CFG, smoke_instance
@@ -31,15 +35,19 @@ PHASE_METRICS = ("wall_s", "flops", "bytes_accessed", "peak_temp_bytes",
 
 def run_profile(out_path: str = "BENCH_profile.json", csv=None) -> dict:
     inst = smoke_instance()
+    dev = jax.devices()[0]
+    hw = hardware(dev.device_kind) if dev.platform == "tpu" else None
     report = {
         "bench": "profile_smoke",
         "backend": jax.default_backend(),
+        "device": {"platform": dev.platform, "kind": dev.device_kind,
+                   "count": jax.device_count()},
         "platform": platform.platform(),
         "impls": {},
     }
     for impl in GRAPH_IMPLS:
         cfg = dataclasses.replace(SMOKE_CFG, graph_impl=impl)
-        prof = profile_solve_round(inst, cfg)
+        prof = profile_solve_round(inst, cfg, hw=hw)
         report["impls"][impl] = prof
         if csv is not None:
             csv.add("profile", f"round/{impl}", "wall_s",

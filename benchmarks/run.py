@@ -29,11 +29,14 @@ from __future__ import annotations
 import sys
 import time
 
+from repro.compile_cache import enable_compile_cache
+
 from benchmarks.common import Csv
 
 
 def main(argv=None) -> None:
     argv = list(argv if argv is not None else sys.argv[1:])
+    enable_compile_cache()
     state_shards = 0
     for a in list(argv):
         if a.startswith("--state-shards"):

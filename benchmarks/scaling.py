@@ -86,11 +86,7 @@ def _per_device_peak(inst, mode, cfg):
     from repro.core.solver import solve_device
     compiled = jax.jit(
         lambda i: solve_device(i, mode=mode, cfg=cfg)).lower(inst).compile()
-    try:
-        ma = compiled.memory_analysis()
-        return None if ma is None else int(ma.temp_size_in_bytes)
-    except Exception:
-        return None
+    return int(compiled.memory_analysis().temp_size_in_bytes)
 
 
 def run_state_sharded(csv, state_shards: int):
@@ -126,13 +122,10 @@ def run_state_sharded(csv, state_shards: int):
     rep = _per_device_peak(inst, "pd", dataclasses.replace(
         CFG, graph_impl="sparse", first_round_cycles45=False))
     sh = _per_device_peak(inst, "pd", cfg)
-    if rep is not None:
-        csv.add("scaling", f"mem-replicated/hw={hw}",
-                "peak_temp_bytes", rep)
-    if sh is not None:
-        csv.add("scaling", f"mem-state-sharded{shards}/hw={hw}",
-                "peak_temp_bytes_per_device", sh)
-    if rep and sh:
+    csv.add("scaling", f"mem-replicated/hw={hw}", "peak_temp_bytes", rep)
+    csv.add("scaling", f"mem-state-sharded{shards}/hw={hw}",
+            "peak_temp_bytes_per_device", sh)
+    if rep:
         csv.add("scaling", f"mem-state-sharded{shards}/hw={hw}",
                 "per_device_vs_replicated", round(sh / rep, 3))
 
@@ -169,11 +162,9 @@ def run_xl_sharded(csv, state_shards: int, hw: int = XL_HW):
     sh = _per_device_peak(inst, "pd", cfg)
     rep = _per_device_peak(inst, "pd", dataclasses.replace(
         XL_CFG, separation_chunk=0, first_round_cycles45=False))
-    if sh is not None:
-        csv.add("scaling", case, "peak_temp_bytes_per_device", sh)
-    if rep is not None:
-        csv.add("scaling", case, "peak_temp_bytes_replicated", rep)
-    if rep and sh:
+    csv.add("scaling", case, "peak_temp_bytes_per_device", sh)
+    csv.add("scaling", case, "peak_temp_bytes_replicated", rep)
+    if rep:
         csv.add("scaling", case, "per_device_vs_replicated",
                 round(sh / rep, 3))
 

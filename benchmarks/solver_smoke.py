@@ -77,13 +77,8 @@ def _compile_solve(inst, mode, cfg):
 
 
 def _peak_memory_bytes(compiled):
-    """XLA's compiled temp-buffer estimate (None where the installed
-    jax/backend can't report it)."""
-    try:
-        ma = compiled.memory_analysis()
-        return None if ma is None else int(ma.temp_size_in_bytes)
-    except Exception:
-        return None
+    """XLA's compiled temp-buffer estimate."""
+    return int(compiled.memory_analysis().temp_size_in_bytes)
 
 
 def run_smoke(out_path: str = "BENCH_solver.json", csv=None) -> dict:
@@ -119,9 +114,8 @@ def run_smoke(out_path: str = "BENCH_solver.json", csv=None) -> dict:
                 if entry[impl]["objective"] is not None:
                     csv.add("smoke", f"{mode}/{impl}", "objective",
                             entry[impl]["objective"])
-                if entry[impl]["peak_mem_bytes"] is not None:
-                    csv.add("smoke", f"{mode}/{impl}", "peak_mem_bytes",
-                            entry[impl]["peak_mem_bytes"])
+                csv.add("smoke", f"{mode}/{impl}", "peak_mem_bytes",
+                        entry[impl]["peak_mem_bytes"])
         report["modes"][mode] = entry
 
     # traced pd solve: wall_traced_s rides in the pd rows (report-only in
@@ -181,11 +175,10 @@ def run_smoke(out_path: str = "BENCH_solver.json", csv=None) -> dict:
     }}
     if csv is not None:
         csv.add("smoke", "pd-state-sharded/sparse", "wall_s", round(t, 4))
-        pm = report["modes"]["pd-state-sharded"]["sparse"][
-            "peak_mem_per_device_bytes"]
-        if pm is not None:
-            csv.add("smoke", "pd-state-sharded/sparse",
-                    "peak_mem_per_device_bytes", pm)
+        csv.add("smoke", "pd-state-sharded/sparse",
+                "peak_mem_per_device_bytes",
+                report["modes"]["pd-state-sharded"]["sparse"][
+                    "peak_mem_per_device_bytes"])
 
     if os.environ.get("RAMA_SMOKE_XL"):
         xl = grid_instance(XL_HW, XL_HW, seed=0)

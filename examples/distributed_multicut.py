@@ -15,7 +15,9 @@ Pipeline (exactly the production path, on 8 faked host devices):
 """
 import os
 
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+os.environ["XLA_FLAGS"] = (
+    os.environ.get("XLA_FLAGS", "")
+    + " --xla_force_host_platform_device_count=8").strip()
 
 import sys
 
@@ -26,6 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import api
+from repro.compile_cache import enable_compile_cache
 from repro.core.dist import (
     make_dist_pd_round, merge_blocks_quotient, partition_instance,
 )
@@ -38,6 +41,7 @@ BLK_EDGES = 8192
 
 
 def main():
+    enable_compile_cache()
     mesh = make_debug_mesh(4, 2)
     n_blocks = mesh.size
     print(f"mesh: {dict(mesh.shape)} ({n_blocks} devices)")

@@ -22,6 +22,7 @@ sys.path.insert(0, "src")
 import numpy as np
 
 from repro import api
+from repro.compile_cache import enable_compile_cache
 from repro.core.baselines import gaec, objective
 from repro.core.graph import grid_instance
 
@@ -40,6 +41,7 @@ def render(labels, h, w):
 
 
 def main():
+    enable_compile_cache()
     inst = grid_instance(H, W, seed=3, n_segments=5)
     cfg = api.SolverConfig(max_neg=4096, max_tri_per_edge=8, nbr_k=8,
                            mp_iters=10, contract_frac=0.5, max_rounds=40)

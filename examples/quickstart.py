@@ -11,11 +11,13 @@ import sys
 sys.path.insert(0, "src")
 
 from repro import api
+from repro.compile_cache import enable_compile_cache
 from repro.core.baselines import gaec, objective
 from repro.core.graph import random_instance
 
 
 def main():
+    enable_compile_cache()
     inst = random_instance(n=200, p=0.08, seed=0, pad_edges=4096,
                            pad_nodes=256)
     cfg = api.SolverConfig(max_neg=1024, max_tri_per_edge=8, mp_iters=10)

@@ -25,6 +25,7 @@ sys.path.insert(0, "src")
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.graph import grid_instance
 from repro.serve import BucketPolicy, SolveEngine, default_router
 
@@ -53,6 +54,7 @@ def make_tiles(seed: int = 0):
 
 
 def main():
+    enable_compile_cache()
     tiles = make_tiles()
     insts = [t[3] for t in tiles]
     print(f"== serving {len(insts)} segmentation tiles "

@@ -264,9 +264,9 @@ def _make_registry(maxsize: int):
                     "mutually exclusive (one device axis): route large "
                     "instances to separation sharding OR shard the batch "
                     "axis")
+            from jax import shard_map
             from jax.sharding import PartitionSpec as P
 
-            from repro.compat import shard_map
             from repro.core.dist import batch_mesh
             fn = shard_map(fn, mesh=batch_mesh(batch_shards),
                            in_specs=P("batch"), out_specs=P("batch"),

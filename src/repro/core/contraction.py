@@ -32,7 +32,9 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.core.graph import CsrGraph, MulticutInstance, csr_lookup_edge
+from repro.core.graph import (
+    CsrGraph, MulticutInstance, csr_lookup_edge, tree_sum,
+)
 from repro.sparse.segment_ops import segment_argmax
 
 
@@ -208,7 +210,7 @@ def _contract_core(inst: MulticutInstance, S: jax.Array):
 
     fu, fv = f[inst.u], f[inst.v]
     self_loop = inst.edge_valid & (fu == fv)
-    gain = jnp.sum(jnp.where(self_loop, inst.cost, 0.0))
+    gain = tree_sum(jnp.where(self_loop, inst.cost, 0.0))
     valid = inst.edge_valid & ~self_loop
 
     # the one sort: 2E directed copies by (src, dst, original edge id);

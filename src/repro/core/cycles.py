@@ -38,9 +38,9 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core.graph import (
     CsrGraph, DEFAULT_SPARSE_THRESHOLD, MulticutInstance, csr_filter,
     csr_from_instance, csr_lookup_edge, csr_row_window, resolve_graph_impl,

@@ -32,11 +32,10 @@ from functools import lru_cache, partial
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
-
-from repro.core.graph import MulticutInstance
+from repro.core.graph import MulticutInstance, tree_sum
 from repro.core.solver import SolverConfig, fused_pd_round
 
 
@@ -145,23 +144,6 @@ def gather_edge_field(x_local: jax.Array, ids: jax.Array,
     gathered = jax.lax.all_gather(vals, axis)          # (S, ...) halo buffer
     owner = jnp.clip(ids // E_loc, 0, gathered.shape[0] - 1)
     return jnp.take_along_axis(gathered, owner[None], axis=0)[0]
-
-
-def tree_sum(x: jax.Array) -> jax.Array:
-    """Sum along the LAST axis by an explicit pairwise halving tree of
-    elementwise adds. ``jnp.sum`` lowers to an XLA reduce whose float
-    accumulation order is a compiler choice — it can change with the
-    surrounding program (fusion context), which breaks bit-reproducibility
-    across shard counts even at identical reduce widths. Spelling the tree
-    out as adds of distinct tensors pins the float DAG to the (static)
-    width alone: same width → same bits, on every backend."""
-    while x.shape[-1] > 1:
-        half = x.shape[-1] // 2
-        paired = x[..., : 2 * half : 2] + x[..., 1: 2 * half : 2]
-        if x.shape[-1] % 2:
-            paired = jnp.concatenate([paired, x[..., -1:]], axis=-1)
-        x = paired
-    return x[..., 0]
 
 
 def blocked_sum(x_local: jax.Array, shards: int, axis: str = STATE_AXIS,

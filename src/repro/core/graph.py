@@ -84,6 +84,25 @@ def check_edge_addressing(num_edges: int, where: str = "build_csr") -> None:
 DEFAULT_SPARSE_THRESHOLD = 256
 
 
+def tree_sum(x: jax.Array) -> jax.Array:
+    """Sum along the LAST axis by an explicit pairwise halving tree of
+    elementwise adds. ``jnp.sum`` lowers to an XLA reduce whose float
+    accumulation order is a compiler choice: it changes with the
+    surrounding program (fusion context, shard count) and, on the TPU,
+    with a vmapped batch axis, so the same instance solved alone and in a
+    batch would report lower bounds that differ in the last bits.
+    Spelling the tree out as adds of distinct tensors pins the float DAG
+    to the (static) width alone: same width → same bits, batched or not,
+    on every backend."""
+    while x.shape[-1] > 1:
+        half = x.shape[-1] // 2
+        paired = x[..., : 2 * half : 2] + x[..., 1: 2 * half : 2]
+        if x.shape[-1] % 2:
+            paired = jnp.concatenate([paired, x[..., -1:]], axis=-1)
+        x = paired
+    return x[..., 0]
+
+
 class MulticutInstance(NamedTuple):
     u: jax.Array            # (E,) int32, u < v for valid edges
     v: jax.Array            # (E,) int32
@@ -103,7 +122,7 @@ class MulticutInstance(NamedTuple):
         """Multicut objective <c, y>: sum of costs of cut edges under a node
         labeling (y_e = 1 iff endpoints in distinct clusters)."""
         cut = labels[self.u] != labels[self.v]
-        return jnp.sum(jnp.where(self.edge_valid & cut, self.cost, 0.0))
+        return tree_sum(jnp.where(self.edge_valid & cut, self.cost, 0.0))
 
 
 def make_instance(u, v, cost, num_nodes: int, pad_edges: int | None = None,

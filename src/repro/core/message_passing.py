@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.cycles import Triangles
-from repro.core.graph import MulticutInstance
+from repro.core.graph import MulticutInstance, tree_sum
 
 
 class MPState(NamedTuple):
@@ -46,12 +46,45 @@ def edge_degree(state: MPState, num_edges: int) -> jax.Array:
                                num_segments=num_edges)
 
 
+def ordered_segment_sum(x: jax.Array, ids: jax.Array,
+                        num_segments: int) -> jax.Array:
+    """``jax.ops.segment_sum`` with its float order pinned: each segment
+    sums its entries in ascending position, ``((0 + x_a) + x_b) + ...``,
+    the order a sequential scatter-add applies them in.
+
+    A float scatter-add leaves that order to the compiler, and XLA's CPU
+    backend changes it with the surrounding program, so the same
+    message-passing step gave other bits in the solve with sharded
+    separation. Here each pass lets every segment take its lowest-position
+    entry not yet taken (an integer scatter-min, exact in any order) and
+    add it: one float add per segment per pass, as many passes as the
+    longest segment has entries. No sort. Ids outside
+    ``[0, num_segments)`` are dropped, as ``segment_sum`` drops them."""
+    n = x.shape[0]
+    pos = jnp.arange(n, dtype=jnp.int32)
+    live0 = (ids >= 0) & (ids < num_segments)
+
+    def body(carry):
+        live, acc = carry
+        tgt = jnp.where(live, ids, num_segments)
+        first = jnp.full((num_segments,), n, jnp.int32).at[tgt].min(
+            pos, mode="drop")
+        take = live & (first[jnp.clip(ids, 0, num_segments - 1)] == pos)
+        acc = acc.at[jnp.where(take, ids, num_segments)].add(x, mode="drop")
+        return live & ~take, acc
+
+    _, acc = jax.lax.while_loop(
+        lambda c: jnp.any(c[0]), body,
+        (live0, jnp.zeros((num_segments,), x.dtype)))
+    return acc
+
+
 def reparametrized_costs(cost, state: MPState) -> jax.Array:
     """c^λ_e = c_e + Σ_{t ∋ e} λ_{t,e} = c_e − Σ t_cost."""
     E = cost.shape[0]
-    ids = state.tri.reshape(-1)
+    ids = jnp.where(state.tri_valid[:, None], state.tri, -1).reshape(-1)
     contrib = jnp.where(state.tri_valid[:, None], -state.t_cost, 0.0).reshape(-1)
-    return cost + jax.ops.segment_sum(contrib, ids, num_segments=E)
+    return cost + ordered_segment_sum(contrib, ids, E)
 
 
 def triangle_min_marginals(t_cost: jax.Array):
@@ -123,11 +156,12 @@ def mp_sweep_reference(t_cost: jax.Array) -> jax.Array:
 def lower_bound(cost, edge_valid, state: MPState) -> jax.Array:
     """LB(λ) of (5): Σ_e min(0, c^λ_e) + Σ_t min_{y∈M_T} ⟨c_t^λ, y⟩."""
     c_rep = reparametrized_costs(cost, state)
-    lb_e = jnp.sum(jnp.where(edge_valid, jnp.minimum(0.0, c_rep), 0.0))
+    lb_e = tree_sum(jnp.where(edge_valid, jnp.minimum(0.0, c_rep), 0.0))
     a, b, c = state.t_cost[:, 0], state.t_cost[:, 1], state.t_cost[:, 2]
     states = jnp.stack([jnp.zeros_like(a), a + b, a + c, b + c, a + b + c],
                        axis=-1)
-    lb_t = jnp.sum(jnp.where(state.tri_valid, jnp.min(states, axis=-1), 0.0))
+    lb_t = tree_sum(jnp.where(state.tri_valid, jnp.min(states, axis=-1),
+                              0.0))
     return lb_e + lb_t
 
 
@@ -156,7 +190,7 @@ def run_message_passing_sharded(cost_local, edge_valid_local, tri, tri_valid,
     two-depth trip-count correction, exactly like the replicated
     :func:`run_message_passing`."""
     from repro.core.dist import STATE_AXIS, blocked_sum, edge_range_start, \
-        gather_edge_field, tree_sum
+        gather_edge_field
     if axis is None:
         axis = STATE_AXIS
     T = tri.shape[0]

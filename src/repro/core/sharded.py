@@ -47,9 +47,9 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core.contraction import (
     choose_contraction_set_sharded, contract_sharded,
 )

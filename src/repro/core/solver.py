@@ -49,7 +49,7 @@ from repro.core.contraction import (
 from repro.core.cycles import separate
 from repro.core.graph import (
     DEFAULT_SPARSE_THRESHOLD, GRAPH_IMPLS, CsrGraph, MulticutInstance,
-    csr_from_instance, resolve_graph_impl,
+    csr_from_instance, resolve_graph_impl, tree_sum,
 )
 from repro.core.message_passing import init_mp, run_message_passing
 from repro.obs.trace import init_trace, trace_set_round
@@ -231,8 +231,8 @@ def _dual_round_core(inst: MulticutInstance, cfg: SolverConfig,
     if not with_aux:
         return inst2, c_rep, lb, sep.csr
     n_cyc = jnp.sum(sep.triangles.valid).astype(jnp.int32)
-    trivial_lb = jnp.sum(jnp.where(inst2.edge_valid,
-                                   jnp.minimum(0.0, inst2.cost), 0.0))
+    trivial_lb = tree_sum(jnp.where(inst2.edge_valid,
+                                    jnp.minimum(0.0, inst2.cost), 0.0))
     return inst2, c_rep, lb, sep.csr, (n_cyc, lb - trivial_lb)
 
 
@@ -543,8 +543,8 @@ def _solve_d_device(inst: MulticutInstance, cfg: SolverConfig, sweep=None,
                                 cfg.sparse_threshold) == "sparse"
 
     def lb_parts(cur2, c_rep, lb, tri_lb_sum):
-        edge_lb = jnp.sum(jnp.where(cur2.edge_valid,
-                                    jnp.minimum(0.0, c_rep), 0.0))
+        edge_lb = tree_sum(jnp.where(cur2.edge_valid,
+                                     jnp.minimum(0.0, c_rep), 0.0))
         tri_lb_sum = tri_lb_sum + (lb - edge_lb)
         return tri_lb_sum, tri_lb_sum + edge_lb
 

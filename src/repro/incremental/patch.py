@@ -35,7 +35,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.graph import (
-    CsrGraph, MulticutInstance, csr_lookup_edge, splice_csr,
+    CsrGraph, MulticutInstance, csr_lookup_edge, splice_csr, tree_sum,
 )
 
 __all__ = ["DeltaPatch", "PatchInfo", "apply_patch", "apply_patch_host",
@@ -233,7 +233,7 @@ def apply_patch(inst: MulticutInstance, csr: CsrGraph, patch: DeltaPatch):
         n_deleted=jnp.sum(is_del).astype(jnp.int32),
         n_reweighted=jnp.sum(upd).astype(jnp.int32),
         n_dropped=jnp.sum(fresh & ~ok_alloc).astype(jnp.int32),
-        lb_slack=jnp.sum(jnp.minimum(0.0, delta)).astype(jnp.float32))
+        lb_slack=tree_sum(jnp.minimum(0.0, delta)).astype(jnp.float32))
     return inst2, csr2, info
 
 

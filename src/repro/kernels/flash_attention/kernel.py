@@ -46,12 +46,9 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, *, block_q: int, block_k: int,
 
     def body(kv_i, carry):
         m_prev, l_prev, acc = carry
-        # leading index as a traced scalar: a bare python 0 breaks the
-        # load-discharge rule of older pallas (no .shape on int)
-        k = pl.load(k_ref, (jnp.int32(0), pl.dslice(kv_i * block_k, block_k),
-                            slice(None))).astype(jnp.float32)
-        v = pl.load(v_ref, (jnp.int32(0), pl.dslice(kv_i * block_k, block_k),
-                            slice(None))).astype(jnp.float32)
+        kv = pl.ds(kv_i * block_k, block_k)
+        k = k_ref[0, kv, :].astype(jnp.float32)
+        v = v_ref[0, kv, :].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         if softcap is not None:

@@ -1,5 +1,7 @@
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["XLA_FLAGS"] = (
+    os.environ.get("XLA_FLAGS", "")
+    + " --xla_force_host_platform_device_count=512").strip()
 
 """Multi-pod dry-run: lower + compile every (architecture × input shape)
 cell on the production meshes and record memory/cost analysis.

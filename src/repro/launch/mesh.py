@@ -11,25 +11,17 @@ from __future__ import annotations
 import jax
 
 
-def _axis_type_kwargs(n_axes: int) -> dict:
-    """``axis_types`` only exists on newer JAX; omit it elsewhere (the old
-    default — fully auto axes — is what we ask for anyway)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_debug_mesh(n_data: int = 1, n_model: int = 1):
     """Small mesh for tests (requires xla_force_host_platform_device_count)."""
     return jax.make_mesh((n_data, n_model), ("data", "model"),
-                         **_axis_type_kwargs(2))
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 def data_axes(mesh) -> tuple:

@@ -29,9 +29,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-
-from repro.compat import shard_map
 
 
 class PartitionedTriplets(NamedTuple):

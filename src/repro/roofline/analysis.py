@@ -28,14 +28,32 @@ import re
 
 @dataclasses.dataclass(frozen=True)
 class Hardware:
-    name: str = "TPU v5e"
-    peak_flops: float = 197e12      # bf16 FLOP/s per chip
-    hbm_bw: float = 819e9           # bytes/s per chip
-    ici_bw: float = 50e9            # bytes/s per ICI link (~50 GB/s)
-    hbm_bytes: float = 16 * 2**30   # capacity per chip
+    name: str
+    peak_flops: float       # bf16 FLOP/s per chip
+    hbm_bw: float           # bytes/s per chip
+    ici_bw: float           # bytes/s per ICI link
+    hbm_bytes: float        # capacity per chip
 
 
-HW = Hardware()
+# Published per-chip peaks, keyed by jax's ``device_kind``. TPU v5e: Google
+# Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16, 16 GB of HBM at
+# 819 GB/s, 1,600 Gbit/s of interconnect (four links, ~50 GB/s each).
+PEAKS = {
+    "TPU v5 lite": Hardware(name="TPU v5e", peak_flops=197e12,
+                            hbm_bw=819e9, ici_bw=50e9,
+                            hbm_bytes=16 * 2**30),
+}
+
+
+def hardware(device_kind: str) -> Hardware:
+    """The peaks of ``device_kind``; a device missing from :data:`PEAKS`
+    is an error, never a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r}; known: {sorted(PEAKS)}") from None
+
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -95,7 +113,7 @@ def collective_bytes(hlo_text: str) -> dict:
 
 
 def roofline_terms(flops: float, bytes_accessed: float, coll_bytes: float,
-                   hw: Hardware = HW) -> dict:
+                   hw: Hardware) -> dict:
     compute_s = flops / hw.peak_flops
     memory_s = bytes_accessed / hw.hbm_bw
     collective_s = coll_bytes / hw.ici_bw
@@ -117,7 +135,7 @@ def step_time_estimate(terms: dict, overlap: bool = True) -> float:
 
 
 def roofline_fraction(model_flops_per_device: float, terms: dict,
-                      hw: Hardware = HW) -> float:
+                      hw: Hardware) -> float:
     """Fraction of peak the step achieves under the roofline estimate:
     useful-FLOPs-time / estimated step time."""
     t = step_time_estimate(terms)

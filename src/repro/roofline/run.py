@@ -27,9 +27,13 @@ import jax.numpy as jnp
 from repro.configs.base import REGISTRY, get_arch, all_arch_ids
 from repro.launch.mesh import make_production_mesh
 from repro.roofline.analysis import (
-    HW, collective_bytes, dominant_term, roofline_terms, roofline_fraction,
-    step_time_estimate,
+    collective_bytes, dominant_term, hardware, roofline_fraction,
+    roofline_terms, step_time_estimate,
 )
+
+# the chip the production meshes describe; the dry run compiles on faked
+# host devices, so the target is named, not read from jax.devices()
+HW = hardware("TPU v5 lite")
 
 ASSIGNED = [
     "granite-34b", "gemma2-9b", "phi3-mini-3.8b", "llama4-scout-17b-a16e",
@@ -90,7 +94,7 @@ def measure_cell(arch_id: str, shape_name: str, mesh):
         flops, bytes_, coll, _ = _measure(arch, shape_name, mesh)
 
     n_chips = mesh.size
-    terms = roofline_terms(flops, bytes_, coll["total"])
+    terms = roofline_terms(flops, bytes_, coll["total"], HW)
     # CPU-backend dtype correction: XLA:CPU upcasts bf16 dot operands to
     # f32, so activation/weight collectives and HBM traffic are measured at
     # 2x their TPU size for bf16-compute archs (verified: param dtype
@@ -100,7 +104,7 @@ def measure_cell(arch_id: str, shape_name: str, mesh):
     dtype_bf16 = getattr(getattr(arch, "cfg", None), "dtype", None) == \
         jnp.bfloat16 or getattr(arch, "compute_dtype", None) == jnp.bfloat16
     if dtype_bf16:
-        corr = roofline_terms(flops, bytes_ / 2, coll["total"] / 2)
+        corr = roofline_terms(flops, bytes_ / 2, coll["total"] / 2, HW)
         terms_corr = {f"{k}_corr": round(v, 6) for k, v in corr.items()}
     else:
         terms_corr = {f"{k}_corr": round(terms[k], 6) for k in terms}
@@ -123,10 +127,12 @@ def measure_cell(arch_id: str, shape_name: str, mesh):
         "dominant": dominant_term(terms),
         "model_flops_per_chip": model_flops,
         "useful_flop_ratio": round(model_flops / flops, 4) if flops else 0,
-        "roofline_fraction": round(roofline_fraction(model_flops, terms), 4),
+        "roofline_fraction": round(roofline_fraction(model_flops, terms, HW),
+                                    4),
         "roofline_fraction_corr": round(roofline_fraction(
             model_flops,
-            {k.replace("_corr", ""): v for k, v in terms_corr.items()}), 4),
+            {k.replace("_corr", ""): v for k, v in terms_corr.items()},
+            HW), 4),
         "step_time_est_s": round(step_time_estimate(terms), 6),
         "analysis_s": round(time.time() - t0, 1),
     }

@@ -45,7 +45,7 @@ from repro.core.graph import (
 from repro.core.message_passing import init_mp, run_message_passing
 from repro.core.solver import SolverConfig, resolve_intersect, resolve_sweep
 from repro.roofline.analysis import (
-    HW, Hardware, collective_bytes, dominant_term, roofline_terms,
+    Hardware, collective_bytes, dominant_term, roofline_terms,
     step_time_estimate,
 )
 
@@ -82,15 +82,24 @@ def _compiled_stats(compiled) -> dict:
     }
 
 
-def _phase_record(stats: dict, wall_s: float, hw: Hardware) -> dict:
-    terms = roofline_terms(stats["flops"], stats["bytes_accessed"],
-                           stats["collective_bytes"], hw)
-    return {**stats, "wall_s": wall_s, "terms": terms,
-            "dominant": dominant_term(terms),
+def _roofline(flops: float, bytes_accessed: float, coll_bytes: float,
+              hw: Hardware | None) -> dict:
+    """Roofline fields of a phase record; all None ("not measured") when
+    the run has no chip peaks to hold its counts against."""
+    if hw is None:
+        return {"terms": None, "dominant": None, "roofline_s": None}
+    terms = roofline_terms(flops, bytes_accessed, coll_bytes, hw)
+    return {"terms": terms, "dominant": dominant_term(terms),
             "roofline_s": step_time_estimate(terms)}
 
 
-def _profile(fn, args, hw: Hardware) -> tuple[dict, object]:
+def _phase_record(stats: dict, wall_s: float, hw: Hardware | None) -> dict:
+    return {**stats, "wall_s": wall_s,
+            **_roofline(stats["flops"], stats["bytes_accessed"],
+                        stats["collective_bytes"], hw)}
+
+
+def _profile(fn, args, hw: Hardware | None) -> tuple[dict, object]:
     compiled = jax.jit(fn).lower(*args).compile()
     rec = _phase_record(_compiled_stats(compiled), _wall(compiled, *args),
                         hw)
@@ -100,7 +109,7 @@ def _profile(fn, args, hw: Hardware) -> tuple[dict, object]:
 def profile_solve_round(inst: MulticutInstance,
                         cfg: SolverConfig = SolverConfig(),
                         backend: str | None = None,
-                        hw: Hardware = HW) -> dict:
+                        hw: Hardware | None = None) -> dict:
     """Per-phase flops/bytes/wall attribution of one full separation +
     message-passing + contraction round on ``inst`` under ``cfg``.
 
@@ -109,6 +118,10 @@ def profile_solve_round(inst: MulticutInstance,
     attribution decomposes the round the solver actually runs (modulo
     XLA's cross-phase fusion, which the per-phase walls deliberately
     exclude — their sum bounds the fused round from above).
+
+    ``hw`` holds the counts against the peaks of the chip the walls were
+    measured on (:func:`repro.roofline.analysis.hardware`); without it the
+    roofline fields are None, never another chip's shares of this wall.
 
     ``cfg.state_shards > 1`` profiles the edge-range-partitioned round
     instead (see :func:`_profile_solve_round_sharded`).
@@ -197,15 +210,17 @@ def profile_solve_round(inst: MulticutInstance,
 
     return {
         "impl": impl,
-        "hw": hw.name,
+        "hw": None if hw is None else hw.name,
         "mp_iters": cfg.mp_iters,
         "phases": phases,
         "round_wall_s": sum(p["wall_s"] for p in phases.values()),
-        "round_roofline_s": sum(p["roofline_s"] for p in phases.values()),
+        "round_roofline_s": (None if hw is None else
+                             sum(p["roofline_s"] for p in phases.values())),
     }
 
 
-def _sharded_phase_record(per_dev: dict, wall_s: float, hw: Hardware,
+def _sharded_phase_record(per_dev: dict, wall_s: float,
+                          hw: Hardware | None,
                           shards: int) -> dict:
     """Phase record for a shard_map'd executable: ``cost_analysis`` is
     per-program, and SPMD programs are identical, so the whole-job total
@@ -224,16 +239,14 @@ def _sharded_phase_record(per_dev: dict, wall_s: float, hw: Hardware,
         "bytes_accessed_per_device": per_dev["bytes_accessed"],
         "collective_bytes_per_device": per_dev["collective_bytes"],
     }
-    terms = roofline_terms(per_dev["flops"], per_dev["bytes_accessed"],
-                           per_dev["collective_bytes"], hw)
-    return {**job, "wall_s": wall_s, "terms": terms,
-            "dominant": dominant_term(terms),
-            "roofline_s": step_time_estimate(terms)}
+    return {**job, "wall_s": wall_s,
+            **_roofline(per_dev["flops"], per_dev["bytes_accessed"],
+                        per_dev["collective_bytes"], hw)}
 
 
 def _profile_solve_round_sharded(inst: MulticutInstance, cfg: SolverConfig,
                                  backend: str | None,
-                                 hw: Hardware) -> dict:
+                                 hw: Hardware | None) -> dict:
     """Per-phase attribution of one edge-range-partitioned PD round
     (:mod:`repro.core.sharded`): separation / message passing /
     contraction each compiled as its own ``jit(shard_map(...))`` over the
@@ -246,9 +259,9 @@ def _profile_solve_round_sharded(inst: MulticutInstance, cfg: SolverConfig,
     inside the phase executable); and each record carries
     ``*_per_device`` alongside the whole-job totals (see
     :func:`_sharded_phase_record`)."""
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
     from repro.core.contraction import (
         choose_contraction_set_sharded, contract_sharded,
     )
@@ -333,10 +346,11 @@ def _profile_solve_round_sharded(inst: MulticutInstance, cfg: SolverConfig,
 
     return {
         "impl": "sparse",
-        "hw": hw.name,
+        "hw": None if hw is None else hw.name,
         "mp_iters": cfg.mp_iters,
         "state_shards": shards,
         "phases": phases,
         "round_wall_s": sum(p["wall_s"] for p in phases.values()),
-        "round_roofline_s": sum(p["roofline_s"] for p in phases.values()),
+        "round_roofline_s": (None if hw is None else
+                             sum(p["roofline_s"] for p in phases.values())),
     }

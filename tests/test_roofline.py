@@ -9,9 +9,11 @@ import numpy as np
 import pytest
 
 from repro.roofline.analysis import (
-    HW, collective_bytes, dominant_term, roofline_fraction, roofline_terms,
-    step_time_estimate,
+    PEAKS, collective_bytes, dominant_term, hardware, roofline_fraction,
+    roofline_terms, step_time_estimate,
 )
+
+HW = hardware("TPU v5 lite")
 
 HLO_SAMPLE = """
 HloModule jit_step
@@ -39,7 +41,7 @@ def test_collective_bytes_ignores_elementwise():
 
 def test_roofline_terms_units():
     terms = roofline_terms(flops=HW.peak_flops, bytes_accessed=HW.hbm_bw,
-                           coll_bytes=HW.ici_bw)
+                           coll_bytes=HW.ici_bw, hw=HW)
     assert terms["compute_s"] == pytest.approx(1.0)
     assert terms["memory_s"] == pytest.approx(1.0)
     assert terms["collective_s"] == pytest.approx(1.0)
@@ -61,11 +63,31 @@ def test_step_time_overlap_vs_serial():
 def test_roofline_fraction_bounds():
     terms = {"compute_s": 1.0, "memory_s": 0.5, "collective_s": 0.1}
     # if all HLO flops were useful, fraction == compute_s / step_time == 1
-    frac = roofline_fraction(HW.peak_flops * 1.0, terms)
+    frac = roofline_fraction(HW.peak_flops * 1.0, terms, HW)
     assert frac == pytest.approx(1.0)
     # half-useful flops -> 0.5
-    frac = roofline_fraction(HW.peak_flops * 0.5, terms)
+    frac = roofline_fraction(HW.peak_flops * 0.5, terms, HW)
     assert frac == pytest.approx(0.5)
+
+
+def test_hardware_table_keyed_by_device_kind():
+    """Peaks come from the table, keyed by jax's device_kind; a device
+    missing from it (the CPU included) is an error, never a default."""
+    assert HW is PEAKS["TPU v5 lite"]
+    assert HW.peak_flops == 197e12 and HW.hbm_bw == 819e9
+    with pytest.raises(KeyError, match="no published peaks"):
+        hardware(jax.devices("cpu")[0].device_kind)
+
+
+def test_roofline_fields_need_chip_peaks():
+    """Without a chip's peaks a phase record's roofline fields are None
+    ("not measured"), not one chip's shares of another device's wall."""
+    from repro.roofline.solver import _roofline
+    assert _roofline(1e9, 1e9, 0.0, None) == {
+        "terms": None, "dominant": None, "roofline_s": None}
+    rec = _roofline(1e9, 1e9, 0.0, HW)
+    assert rec["dominant"] == "memory"
+    assert rec["roofline_s"] == pytest.approx(1e9 / HW.hbm_bw)
 
 
 def test_collective_bytes_on_real_compile():
@@ -99,10 +121,12 @@ def test_profile_solve_round(impl):
     prof = profile_solve_round(inst, cfg)
     assert prof["impl"] == impl
     assert set(prof["phases"]) == set(PHASES)
+    assert prof["hw"] is None and prof["round_roofline_s"] is None
     for rec in prof["phases"].values():
         assert rec["wall_s"] > 0
         assert rec["flops"] >= 0 and rec["bytes_accessed"] > 0
-        assert rec["dominant"] in ("compute", "memory", "collective")
+        # CPU walls: no chip peaks, so no roofline share
+        assert rec["dominant"] is None and rec["roofline_s"] is None
     loop = prof["phases"]["message_passing"]["loop"]
     assert loop["iters"] == cfg.mp_iters
     # depth-2 does strictly more work than depth-1
@@ -132,7 +156,7 @@ def _check_sharded_profile(prof, shards):
             rec["bytes_accessed_per_device"] * shards, name
         assert rec["collective_bytes"] == \
             rec["collective_bytes_per_device"] * shards, name
-        assert rec["dominant"] in ("compute", "memory", "collective"), name
+        assert rec["dominant"] is None, name        # CPU: not measured
     loop = prof["phases"]["message_passing"]["loop"]
     assert loop["flops_depth2"] > loop["flops_depth1"]
     assert prof["round_wall_s"] == pytest.approx(
