@@ -42,18 +42,22 @@ from repro.sparse.segment_ops import segment_argmax
 # Connected components
 # ---------------------------------------------------------------------------
 
-def connected_components(u, v, edge_mask, num_nodes: int):
+def connected_components(u, v, edge_mask, num_nodes: int,
+                         with_aux: bool = False):
     """Min-label propagation with pointer jumping. Returns (N,) labels where
     each node's label is the smallest node id in its component (w.r.t. edges
-    where ``edge_mask`` is True)."""
+    where ``edge_mask`` is True).
+
+    ``with_aux`` (static) also returns the loop's trip count, an i32 scalar
+    (``SolveTrace.cc_iters``); off by default so untraced jaxprs are
+    unchanged."""
     labels0 = jnp.arange(num_nodes, dtype=jnp.int32)
 
     def cond(state):
-        _, changed = state
-        return changed
+        return state[1]
 
     def body(state):
-        labels, _ = state
+        labels = state[0]
         lu, lv = labels[u], labels[v]
         m = jnp.minimum(lu, lv)
         new = labels.at[u].min(jnp.where(edge_mask, m, lu))
@@ -62,10 +66,11 @@ def connected_components(u, v, edge_mask, num_nodes: int):
         new = new[new]
         new = new[new]
         changed = jnp.any(new != labels)
-        return new, changed
+        return (new, changed) + ((state[2] + 1,) if with_aux else ())
 
-    labels, _ = jax.lax.while_loop(cond, body, (labels0, jnp.bool_(True)))
-    return labels
+    init = (labels0, jnp.bool_(True)) + ((jnp.int32(0),) if with_aux else ())
+    out = jax.lax.while_loop(cond, body, init)
+    return (out[0], out[2]) if with_aux else out[0]
 
 
 # ---------------------------------------------------------------------------
@@ -110,18 +115,22 @@ def maximum_matching(inst: MulticutInstance, rounds: int = 3,
 
 
 def spanning_forest_contraction(inst: MulticutInstance, rounds: int = 4,
-                                min_cost: float = 0.0):
+                                min_cost: float = 0.0,
+                                with_aux: bool = False):
     """Borůvka-style maximum spanning forest on attractive edges with
     conflict freezing: a Borůvka round that would place a repulsive edge
     inside a component is reverted for that component (fixed-shape stand-in
-    for the paper's remove-weakest-path-edge repair)."""
+    for the paper's remove-weakest-path-edge repair).
+
+    ``with_aux`` (static) also returns the summed trip counts of the
+    ``2 * rounds`` :func:`connected_components` loops (i32 scalar)."""
     N, E = inst.num_nodes, inst.num_edges
     u, v, cost = inst.u, inst.v, inst.cost
     neg = inst.edge_valid & (cost < 0)
     S = jnp.zeros(E, dtype=bool)
 
     def one_round(carry, _):
-        S, labels = carry
+        S, labels = carry[0], carry[1]
         cl_u, cl_v = labels[u], labels[v]
         active = inst.edge_valid & (cost > min_cost) & (cl_u != cl_v)
         # best outgoing edge per component (keyed by component root label)
@@ -135,23 +144,28 @@ def spanning_forest_contraction(inst: MulticutInstance, rounds: int = 4,
         cand = jnp.zeros(E, dtype=bool).at[jnp.clip(best_edge, 0)].max(best_edge >= 0)
         cand = cand & active
         S_try = S | cand
-        labels_try = connected_components(u, v, S_try, N)
+        cc_try = connected_components(u, v, S_try, N, with_aux=with_aux)
+        labels_try = cc_try[0] if with_aux else cc_try
         # conflict: repulsive edge newly internal to a merged component
         conflict = neg & (labels_try[u] == labels_try[v]) & (labels[u] != labels[v])
         frozen = jnp.zeros(N, dtype=bool).at[labels_try[u]].max(conflict)
         keep = cand & ~frozen[labels_try[u]] & ~frozen[labels_try[v]]
         S_new = S | keep
-        labels_new = connected_components(u, v, S_new, N)
-        return (S_new, labels_new), None
+        cc_new = connected_components(u, v, S_new, N, with_aux=with_aux)
+        if not with_aux:
+            return (S_new, cc_new), None
+        return (S_new, cc_new[0], carry[2] + cc_try[1] + cc_new[1]), None
 
     labels0 = jnp.arange(N, dtype=jnp.int32)
-    (S, _), _ = jax.lax.scan(one_round, (S, labels0), None, length=rounds)
-    return S
+    init = (S, labels0) + ((jnp.int32(0),) if with_aux else ())
+    out, _ = jax.lax.scan(one_round, init, None, length=rounds)
+    return (out[0], out[2]) if with_aux else out[0]
 
 
 def choose_contraction_set(inst: MulticutInstance, matching_rounds: int = 3,
                            forest_rounds: int = 4, switch_frac: float = 0.1,
-                           contract_frac: float = 0.0):
+                           contract_frac: float = 0.0,
+                           with_aux: bool = False):
     """Paper §3.1: matching first; if it matched fewer than
     ``switch_frac * |V|`` edges, use the spanning-forest strategy instead.
     Both branches are computed (fixed-shape) and selected with ``where``.
@@ -164,19 +178,32 @@ def choose_contraction_set(inst: MulticutInstance, matching_rounds: int = 3,
     The forest branch (component freezing) can legitimately return *fewer*
     edges than the matching it was meant to improve on; falling back to an
     empty set would terminate the outer solver while positive edges remain.
-    We therefore never return fewer edges than the matching found."""
+    We therefore never return fewer edges than the matching found.
+
+    The two branches run under the named scopes
+    ``repro.contraction.matching`` and ``repro.contraction.forest``.
+    ``with_aux`` (static) returns ``(S, cc_iters, forest_chosen)``: the
+    forest's summed connected-components trips and whether the forest's
+    set was kept (i32 scalars)."""
     min_cost = 0.0
     if contract_frac > 0.0:
         cmax = jnp.max(jnp.where(inst.edge_valid, inst.cost, 0.0))
         min_cost = contract_frac * jnp.maximum(cmax, 0.0)
-    S_match = maximum_matching(inst, rounds=matching_rounds,
-                               min_cost=min_cost)
+    with jax.named_scope("repro.contraction.matching"):
+        S_match = maximum_matching(inst, rounds=matching_rounds,
+                                   min_cost=min_cost)
     n_nodes = jnp.sum(inst.node_valid)
     enough = jnp.sum(S_match) >= switch_frac * n_nodes
-    S_forest = spanning_forest_contraction(inst, rounds=forest_rounds,
-                                           min_cost=min_cost)
+    with jax.named_scope("repro.contraction.forest"):
+        forest = spanning_forest_contraction(inst, rounds=forest_rounds,
+                                             min_cost=min_cost,
+                                             with_aux=with_aux)
+    S_forest = forest[0] if with_aux else forest
     use_match = enough | (jnp.sum(S_forest) < jnp.sum(S_match))
-    return jnp.where(use_match, S_match, S_forest)
+    S = jnp.where(use_match, S_match, S_forest)
+    if not with_aux:
+        return S
+    return S, forest[1], (~use_match).astype(jnp.int32)
 
 
 # ---------------------------------------------------------------------------
@@ -191,78 +218,97 @@ class ContractionResult(NamedTuple):
     n_contracted: jax.Array    # edges contracted this round
 
 
-def _contract_core(inst: MulticutInstance, S: jax.Array):
+def _contract_core(inst: MulticutInstance, S: jax.Array,
+                   with_aux: bool = False):
     """Shared contraction kernel: relabel endpoints by component, then one
     lexsort over the 2E directed edge copies that both merges parallel
     edges (sum costs, first-occurrence edge ids in (lo, hi) order — the
     same assignment ``coo_dedupe_sum`` used to produce) and yields the
     contracted graph's CSR: unique directed pairs, compacted in place,
     ARE the CSR entries, so ``row_ptr``/``col``/``edge_id`` fall out of
-    the sort the dedupe needs anyway. Returns (ContractionResult, CsrGraph).
+    the sort the dedupe needs anyway. Returns (ContractionResult, CsrGraph),
+    plus the components loop's trip count when ``with_aux`` (static).
+
+    Its parts run under the named scopes ``repro.contraction.components``
+    (components, root ranking, relabelling), ``repro.contraction.merge``
+    (the sort, run compaction, the COO scatter and cost sum) and
+    ``repro.contraction.csr`` (``row_ptr`` and the partner bisect).
     """
     N, E = inst.num_nodes, inst.num_edges
-    labels = connected_components(inst.u, inst.v, S & inst.edge_valid, N)
-    is_root = (labels == jnp.arange(N, dtype=jnp.int32)) & inst.node_valid
-    new_id = jnp.cumsum(is_root.astype(jnp.int32)) - 1
-    f = new_id[labels].astype(jnp.int32)
-    f = jnp.where(inst.node_valid, f, 0)
-    n_new = jnp.sum(is_root)
+    with jax.named_scope("repro.contraction.components"):
+        cc = connected_components(inst.u, inst.v, S & inst.edge_valid, N,
+                                  with_aux=with_aux)
+        labels = cc[0] if with_aux else cc
+        is_root = (labels == jnp.arange(N, dtype=jnp.int32)) \
+            & inst.node_valid
+        new_id = jnp.cumsum(is_root.astype(jnp.int32)) - 1
+        f = new_id[labels].astype(jnp.int32)
+        f = jnp.where(inst.node_valid, f, 0)
+        n_new = jnp.sum(is_root)
 
-    fu, fv = f[inst.u], f[inst.v]
-    self_loop = inst.edge_valid & (fu == fv)
-    gain = tree_sum(jnp.where(self_loop, inst.cost, 0.0))
-    valid = inst.edge_valid & ~self_loop
+    with jax.named_scope("repro.contraction.merge"):
+        fu, fv = f[inst.u], f[inst.v]
+        self_loop = inst.edge_valid & (fu == fv)
+        gain = tree_sum(jnp.where(self_loop, inst.cost, 0.0))
+        valid = inst.edge_valid & ~self_loop
 
-    # the one sort: 2E directed copies by (src, dst, original edge id);
-    # dead copies get sentinel endpoints that sort past every live row
-    eid0 = jnp.arange(E, dtype=jnp.int32)
-    m = jnp.concatenate([valid, valid])
-    src = jnp.where(m, jnp.concatenate([fu, fv]), N).astype(jnp.int32)
-    dst = jnp.where(m, jnp.concatenate([fv, fu]), N).astype(jnp.int32)
-    order = jnp.lexsort((jnp.tile(eid0, 2), dst, src))
-    s, d = src[order], dst[order]
-    w_s = jnp.tile(inst.cost, 2)[order]
-    live = m[order]
-    nnz = 2 * E
+        # the one sort: 2E directed copies by (src, dst, original edge
+        # id); dead copies get sentinel endpoints that sort past every
+        # live row
+        eid0 = jnp.arange(E, dtype=jnp.int32)
+        m = jnp.concatenate([valid, valid])
+        src = jnp.where(m, jnp.concatenate([fu, fv]), N).astype(jnp.int32)
+        dst = jnp.where(m, jnp.concatenate([fv, fu]), N).astype(jnp.int32)
+        order = jnp.lexsort((jnp.tile(eid0, 2), dst, src))
+        s, d = src[order], dst[order]
+        w_s = jnp.tile(inst.cost, 2)[order]
+        live = m[order]
+        nnz = 2 * E
 
-    # runs of equal (src, dst) = unique directed pairs = CSR entries;
-    # compacting run heads to their run rank keeps them sorted (no re-sort)
-    head = jnp.concatenate([jnp.ones((1,), bool),
-                            (s[1:] != s[:-1]) | (d[1:] != d[:-1])])
-    is_new = live & head
-    rid = jnp.cumsum(is_new.astype(jnp.int32)) - 1      # run id per entry
-    cpos = jnp.where(is_new, rid, nnz)
-    cs = jnp.full((nnz,), N, jnp.int32).at[cpos].set(s, mode="drop")
-    cd = jnp.full((nnz,), N, jnp.int32).at[cpos].set(d, mode="drop")
-    row_ptr = jnp.searchsorted(
-        cs, jnp.arange(N + 1, dtype=jnp.int32), side="left").astype(jnp.int32)
+        # runs of equal (src, dst) = unique directed pairs = CSR entries;
+        # compacting run heads to their run rank keeps them sorted (no
+        # re-sort)
+        head = jnp.concatenate([jnp.ones((1,), bool),
+                                (s[1:] != s[:-1]) | (d[1:] != d[:-1])])
+        is_new = live & head
+        rid = jnp.cumsum(is_new.astype(jnp.int32)) - 1  # run id per entry
+        cpos = jnp.where(is_new, rid, nnz)
+        cs = jnp.full((nnz,), N, jnp.int32).at[cpos].set(s, mode="drop")
+        cd = jnp.full((nnz,), N, jnp.int32).at[cpos].set(d, mode="drop")
 
-    # undirected edge ids: forward pairs (src < dst) appear in exactly the
-    # (lo, hi) lexicographic order, so their rank is the new edge id; each
-    # backward pair bisects row ``dst`` for its forward partner's id
-    fwd = cs < cd
-    new_eid = jnp.cumsum(fwd.astype(jnp.int32)) - 1
-    n_unique = jnp.sum(fwd)
-    probe = CsrGraph(row_ptr=row_ptr, col=cd,
-                     edge_id=jnp.where(fwd, new_eid, -1))
-    partner = jax.vmap(lambda a, b: csr_lookup_edge(probe, a, b))(
-        jnp.where(cs < N, cd, 0), cs)
-    eid_c = jnp.where(fwd, new_eid, partner)
-    eid_c = jnp.where(cs < N, eid_c, -1).astype(jnp.int32)
-    csr = CsrGraph(row_ptr=row_ptr, col=cd, edge_id=eid_c)
+    with jax.named_scope("repro.contraction.csr"):
+        row_ptr = jnp.searchsorted(
+            cs, jnp.arange(N + 1, dtype=jnp.int32),
+            side="left").astype(jnp.int32)
 
-    # contracted COO: scatter run heads by new id, segment-sum the costs of
-    # each forward run (entries ascend by original edge id — stable sort —
-    # so the accumulation order is deterministic)
-    fw_dest = jnp.where(fwd, new_eid, E)
-    u2 = jnp.zeros(E, jnp.int32).at[fw_dest].set(cs, mode="drop")
-    v2 = jnp.zeros(E, jnp.int32).at[fw_dest].set(cd, mode="drop")
-    fw_entry = live & (s < d)
-    seg = jnp.where(fw_entry, eid_c[jnp.clip(rid, 0, nnz - 1)], E - 1)
-    c2 = jax.ops.segment_sum(jnp.where(fw_entry, w_s, 0.0), seg,
-                             num_segments=E)
-    ev2 = jnp.arange(E) < n_unique
-    c2 = jnp.where(ev2, c2, 0.0)
+        # undirected edge ids: forward pairs (src < dst) appear in exactly
+        # the (lo, hi) lexicographic order, so their rank is the new edge
+        # id; each backward pair bisects row ``dst`` for its forward
+        # partner's id
+        fwd = cs < cd
+        new_eid = jnp.cumsum(fwd.astype(jnp.int32)) - 1
+        n_unique = jnp.sum(fwd)
+        probe = CsrGraph(row_ptr=row_ptr, col=cd,
+                         edge_id=jnp.where(fwd, new_eid, -1))
+        partner = jax.vmap(lambda a, b: csr_lookup_edge(probe, a, b))(
+            jnp.where(cs < N, cd, 0), cs)
+        eid_c = jnp.where(fwd, new_eid, partner)
+        eid_c = jnp.where(cs < N, eid_c, -1).astype(jnp.int32)
+        csr = CsrGraph(row_ptr=row_ptr, col=cd, edge_id=eid_c)
+
+    with jax.named_scope("repro.contraction.merge"):
+        # contracted COO: scatter run heads by new id, segment-sum the
+        # costs of each forward run (entries ascend by original edge id —
+        # stable sort — so the accumulation order is deterministic)
+        fw_dest = jnp.where(fwd, new_eid, E)
+        u2 = jnp.zeros(E, jnp.int32).at[fw_dest].set(cs, mode="drop")
+        v2 = jnp.zeros(E, jnp.int32).at[fw_dest].set(cd, mode="drop")
+        fw_entry = live & (s < d)
+        seg = jnp.where(fw_entry, eid_c[jnp.clip(rid, 0, nnz - 1)], E - 1)
+        c2 = jax.ops.segment_sum(jnp.where(fw_entry, w_s, 0.0), seg,
+                                 num_segments=E)
+        ev2 = jnp.arange(E) < n_unique
+        c2 = jnp.where(ev2, c2, 0.0)
 
     node_valid = jnp.arange(N) < n_new
     out = MulticutInstance(u=u2, v=v2, cost=c2, edge_valid=ev2,
@@ -270,6 +316,8 @@ def _contract_core(inst: MulticutInstance, S: jax.Array):
     res = ContractionResult(instance=out, mapping=f, n_new=n_new,
                             self_loop_gain=gain,
                             n_contracted=jnp.sum(S & inst.edge_valid))
+    if with_aux:
+        return res, csr, cc[1]
     return res, csr
 
 
@@ -279,13 +327,15 @@ def contract(inst: MulticutInstance, S: jax.Array) -> ContractionResult:
     return _contract_core(inst, S)[0]
 
 
-def contract_csr(inst: MulticutInstance, S: jax.Array):
+def contract_csr(inst: MulticutInstance, S: jax.Array,
+                 with_aux: bool = False):
     """Contract edge set S and also return the contracted graph's
     :class:`CsrGraph` — maintained from the contraction's own sort, NOT a
     fresh ``build_csr`` (bit-identical to one; asserted in
     tests/test_solver_state.py). This is the round-loop path: the solver
-    carries the returned CSR to the next round's separation."""
-    return _contract_core(inst, S)
+    carries the returned CSR to the next round's separation.
+    ``with_aux`` (static) appends the components loop's trip count."""
+    return _contract_core(inst, S, with_aux=with_aux)
 
 
 # ---------------------------------------------------------------------------

@@ -102,13 +102,14 @@ def select_repulsive_edges(inst: MulticutInstance, max_neg: int,
     delta re-solves apply on their first rounds (shape-preserving, so the
     same ``top_k`` serves both data paths; a ``None`` mask compiles to
     exactly the unrestricted jaxpr)."""
-    sel = inst.edge_valid & (inst.cost < threshold)
-    if node_mask is not None:
-        sel = sel & (node_mask[inst.u] | node_mask[inst.v])
-    score = jnp.where(sel, -inst.cost, -jnp.inf)
-    k = min(max_neg, score.shape[0])
-    vals, idx = jax.lax.top_k(score, k)
-    return idx.astype(jnp.int32), vals > 0
+    with jax.named_scope("repro.separation.select"):
+        sel = inst.edge_valid & (inst.cost < threshold)
+        if node_mask is not None:
+            sel = sel & (node_mask[inst.u] | node_mask[inst.v])
+        score = jnp.where(sel, -inst.cost, -jnp.inf)
+        k = min(max_neg, score.shape[0])
+        vals, idx = jax.lax.top_k(score, k)
+        return idx.astype(jnp.int32), vals > 0
 
 
 class Triangles(NamedTuple):
@@ -126,6 +127,23 @@ class CycleSeparationResult(NamedTuple):
     # sparse path — lets D-mode carry its CSR across rounds instead of
     # re-running build_csr's 2E-lexsort every round
     csr: CsrGraph | None = None
+    # the round's telemetry (``separate(..., with_aux=True)``), else None
+    counts: "SeparationCounts | None" = None
+
+
+class SeparationCounts(NamedTuple):
+    """Telemetry of one separation round, i32 scalars (the
+    ``SolveTrace`` leaves of the same names)."""
+    chords_added: jax.Array     # fresh chords written into a free slot
+    chords_dropped: jax.Array   # fresh chords that found no free slot
+    long_rows: jax.Array        # repulsive edges run in the long bucket
+
+
+def _no_chords(long_rows) -> SeparationCounts:
+    """Counts of a round that separated no 4- or 5-cycle."""
+    zero = jnp.int32(0)
+    return SeparationCounts(chords_added=zero, chords_dropped=zero,
+                            long_rows=long_rows)
 
 
 # ---------------------------------------------------------------------------
@@ -140,26 +158,27 @@ def separate_triangles(inst: MulticutInstance, adj: DenseAdj,
     specialised to hop distance 2 — the common-neighbour test is one
     row-AND, i.e. the matmul ``A⁺A⁺`` restricted to the repulsive pairs.)
     top_k over the 0/1 row picks the K smallest common neighbour ids."""
-    neg_idx, neg_ok = select_repulsive_edges(inst, max_neg,
-                                             node_mask=node_mask)
-    i = inst.u[neg_idx]
-    j = inst.v[neg_idx]
-    max_tri_per_edge = min(max_tri_per_edge, inst.num_nodes)
+    with jax.named_scope("repro.separation.triangles"):
+        neg_idx, neg_ok = select_repulsive_edges(inst, max_neg,
+                                                 node_mask=node_mask)
+        i = inst.u[neg_idx]
+        j = inst.v[neg_idx]
+        max_tri_per_edge = min(max_tri_per_edge, inst.num_nodes)
 
-    def per_edge(i_, j_, e_, ok_):
-        common = (adj.Apos[i_] & adj.Apos[j_]).astype(jnp.float32)
-        vals, ks = jax.lax.top_k(common, max_tri_per_edge)
-        good = (vals > 0) & ok_
-        e_ik = adj.eidx[i_, ks]
-        e_jk = adj.eidx[j_, ks]
-        tri = jnp.stack([jnp.full_like(ks, e_), e_ik, e_jk], axis=-1)
-        good = good & (e_ik >= 0) & (e_jk >= 0)
-        return tri, good
+        def per_edge(i_, j_, e_, ok_):
+            common = (adj.Apos[i_] & adj.Apos[j_]).astype(jnp.float32)
+            vals, ks = jax.lax.top_k(common, max_tri_per_edge)
+            good = (vals > 0) & ok_
+            e_ik = adj.eidx[i_, ks]
+            e_jk = adj.eidx[j_, ks]
+            tri = jnp.stack([jnp.full_like(ks, e_), e_ik, e_jk], axis=-1)
+            good = good & (e_ik >= 0) & (e_jk >= 0)
+            return tri, good
 
-    tris, goods = jax.vmap(per_edge)(i, j, neg_idx, neg_ok)
-    tris = tris.reshape(-1, 3).astype(jnp.int32)
-    goods = goods.reshape(-1)
-    return Triangles(edges=jnp.where(goods[:, None], tris, 0), valid=goods)
+        tris, goods = jax.vmap(per_edge)(i, j, neg_idx, neg_ok)
+        tris = tris.reshape(-1, 3).astype(jnp.int32)
+        goods = goods.reshape(-1)
+        return Triangles(edges=jnp.where(goods[:, None], tris, 0), valid=goods)
 
 
 def triangles_from_windows(ci, ei, oki, cj, ej, e_, ok_, K, intersect):
@@ -196,7 +215,7 @@ def separate_triangles_sparse(inst: MulticutInstance, csr_pos: CsrGraph,
                               row_cap: int = 128, row_cap_short: int = 0,
                               intersect=None,
                               chunk: int = 0, shards: int = 1,
-                              node_mask=None) -> Triangles:
+                              node_mask=None, with_aux: bool = False):
     """3-cycles, CSR path: the common-neighbour test is a sorted-row
     intersection of the two endpoints' attractive rows (the paper's CSR
     kernel). Windows are ascending by node id, so taking the first K
@@ -208,42 +227,51 @@ def separate_triangles_sparse(inst: MulticutInstance, csr_pos: CsrGraph,
     degree buckets: edges whose endpoint rows fit in the short window take
     the narrow pass, the rest a chunk-gated pass at full ``row_cap`` —
     bit-identical to the single-cap path (see the bucketing note above
-    :func:`_combine_buckets`)."""
+    :func:`_combine_buckets`). Runs under the named scope
+    ``repro.separation.triangles``; ``with_aux`` (static) returns
+    ``(Triangles, long_rows)``, the repulsive edges the long bucket ran."""
     if intersect is None:
         intersect = intersect_rows_ref
     N = inst.num_nodes
     K = min(max_tri_per_edge, N)
     W = max(K, min(row_cap, N))
     Ws = max(K, min(row_cap_short, N)) if row_cap_short > 0 else W
-    neg_idx, neg_ok = select_repulsive_edges(inst, max_neg,
-                                             node_mask=node_mask)
-    i = inst.u[neg_idx]
-    j = inst.v[neg_idx]
+    with jax.named_scope("repro.separation.triangles"):
+        neg_idx, neg_ok = select_repulsive_edges(inst, max_neg,
+                                                 node_mask=node_mask)
+        i = inst.u[neg_idx]
+        j = inst.v[neg_idx]
 
-    def make_batch(Wb):
-        def batch(csr_pos, i_, j_, e_, ok_):
-            window = jax.vmap(lambda n: csr_row_window(csr_pos, n, Wb))
-            ci, ei, oki = window(i_)            # (B, Wb) each
-            cj, ej, _ = window(j_)
-            return triangles_from_windows(ci, ei, oki, cj, ej, e_, ok_, K,
-                                          intersect)
-        return batch
+        def make_batch(Wb):
+            def batch(csr_pos, i_, j_, e_, ok_):
+                window = jax.vmap(lambda n: csr_row_window(csr_pos, n, Wb))
+                ci, ei, oki = window(i_)            # (B, Wb) each
+                cj, ej, _ = window(j_)
+                return triangles_from_windows(ci, ei, oki, cj, ej, e_, ok_,
+                                              K, intersect)
+            return batch
 
-    if Ws >= W:
-        tris, goods = _map_repulsive_batches(make_batch(W), csr_pos,
-                                             (i, j, neg_idx, neg_ok),
-                                             chunk, shards)
-    else:
-        deg = csr_pos.degrees
-        is_long = (deg[i] > Ws) | (deg[j] > Ws)
-        out_s = _map_repulsive_batches(
-            make_batch(Ws), csr_pos, (i, j, neg_idx, neg_ok & ~is_long),
-            chunk, shards)
-        out_l = _run_long_bucket(
-            make_batch(W), csr_pos, (i, j, neg_idx, neg_ok & is_long),
-            is_long, chunk, shards, Ws, W)
-        tris, goods = _combine_buckets(is_long, out_s, out_l)
-    return Triangles(edges=jnp.where(goods[:, None], tris, 0), valid=goods)
+        if Ws >= W:
+            tris, goods = _map_repulsive_batches(make_batch(W), csr_pos,
+                                                 (i, j, neg_idx, neg_ok),
+                                                 chunk, shards)
+            if with_aux:
+                n_long = jnp.int32(0)
+        else:
+            deg = csr_pos.degrees
+            is_long = (deg[i] > Ws) | (deg[j] > Ws)
+            out_s = _map_repulsive_batches(
+                make_batch(Ws), csr_pos, (i, j, neg_idx, neg_ok & ~is_long),
+                chunk, shards)
+            out_l = _run_long_bucket(
+                make_batch(W), csr_pos, (i, j, neg_idx, neg_ok & is_long),
+                is_long, chunk, shards, Ws, W)
+            tris, goods = _combine_buckets(is_long, out_s, out_l)
+            if with_aux:
+                n_long = jnp.sum(neg_ok & is_long).astype(jnp.int32)
+        tri = Triangles(edges=jnp.where(goods[:, None], tris, 0),
+                        valid=goods)
+    return (tri, n_long) if with_aux else tri
 
 
 # ---------------------------------------------------------------------------
@@ -265,7 +293,7 @@ class ChordAlloc(NamedTuple):
 
 
 def _alloc_chords(inst: MulticutInstance, exists_eid, ch_u, ch_v,
-                  ch_ok) -> ChordAlloc:
+                  ch_ok, with_aux: bool = False):
     """Allocate chord edges (cost 0) from free padded slots; reuse existing
     edges where the chord already exists.
 
@@ -275,6 +303,9 @@ def _alloc_chords(inst: MulticutInstance, exists_eid, ch_u, ch_v,
     Duplicates within the batch resolve to the first requester's slot
     (first occurrence wins), and fresh slots are packed in request order —
     so for a fixed request order, allocation is fully deterministic.
+
+    ``with_aux`` (static) returns ``(ChordAlloc, n_dropped)``: the fresh
+    chords that found no free slot (i32 scalar).
     """
     E = inst.num_edges
     M = ch_u.shape[0]
@@ -334,8 +365,11 @@ def _alloc_chords(inst: MulticutInstance, exists_eid, ch_u, ch_v,
     own = jnp.where(need & ok_alloc[first_idx], slot[first_idx], -1)
     chord_eid = jnp.where(exists, exists_eid, own).astype(jnp.int32)
     chord_ok = ch_ok & (chord_eid >= 0) & (lo != hi)
-    return ChordAlloc(instance=inst2, eid=chord_eid, ok=chord_ok,
-                      add_u=lo, add_v=hi, add_eid=slot, add_ok=ok_alloc)
+    alloc = ChordAlloc(instance=inst2, eid=chord_eid, ok=chord_ok,
+                       add_u=lo, add_v=hi, add_eid=slot, add_ok=ok_alloc)
+    if with_aux:
+        return alloc, jnp.sum(fresh & ~fits).astype(jnp.int32)
+    return alloc
 
 
 # ---------------------------------------------------------------------------
@@ -367,7 +401,7 @@ def _assemble_cycles45(v0, v4, b1, b2, b3, is4, found, lookup,
 def _alloc_and_assemble(inst: MulticutInstance, lookup, v0, v4, b1, b2, b3,
                         is4, found,
                         splice_into: CsrGraph | None = None,
-                        ) -> CycleSeparationResult:
+                        with_aux: bool = False) -> CycleSeparationResult:
     """Allocate/assemble phase shared by both data paths: resolve the
     winning pairs' chords in canonical (repulsive-edge-index, chord-kind)
     order — chord 1 = (v1, v4) and chord 2 = (v2, v4) interleaved in ONE
@@ -375,25 +409,35 @@ def _alloc_and_assemble(inst: MulticutInstance, lookup, v0, v4, b1, b2, b3,
     assignment a function of the candidates alone, independent of how the
     candidate phase was chunked or sharded. ``splice_into`` (the caller's
     all-edges CSR of ``inst``) additionally splices the fresh chords into
-    that CSR — bit-identical to rebuilding it from the chorded instance."""
-    lo1, hi1 = jnp.minimum(b1, v4), jnp.maximum(b1, v4)
-    lo2, hi2 = jnp.minimum(b2, v4), jnp.maximum(b2, v4)
-    ex = jnp.stack([lookup(lo1, hi1), lookup(lo2, hi2)], axis=1).reshape(-1)
-    ch_u = jnp.stack([b1, b2], axis=1).reshape(-1)
-    ch_v = jnp.stack([v4, v4], axis=1).reshape(-1)
-    need = jnp.stack([found, found & ~is4], axis=1).reshape(-1)
-    a = _alloc_chords(inst, ex, ch_u, ch_v, need)
-    eid = a.eid.reshape(-1, 2)
-    ok = a.ok.reshape(-1, 2)
-    tri = _assemble_cycles45(v0, v4, b1, b2, b3, is4, found, lookup,
-                             eid[:, 0], ok[:, 0], eid[:, 1], ok[:, 1])
-    csr2 = None
-    if splice_into is not None:
-        no_drop = jnp.zeros((inst.num_edges,), bool)
-        csr2 = splice_csr(splice_into, no_drop, a.add_u, a.add_v,
-                          a.add_eid, a.add_ok)
+    that CSR — bit-identical to rebuilding it from the chorded instance.
+    Runs under the named scope ``repro.separation.chords``; ``with_aux``
+    (static) fills ``counts`` (chords added and dropped; ``long_rows`` 0)."""
+    with jax.named_scope("repro.separation.chords"):
+        lo1, hi1 = jnp.minimum(b1, v4), jnp.maximum(b1, v4)
+        lo2, hi2 = jnp.minimum(b2, v4), jnp.maximum(b2, v4)
+        ex = jnp.stack([lookup(lo1, hi1), lookup(lo2, hi2)],
+                       axis=1).reshape(-1)
+        ch_u = jnp.stack([b1, b2], axis=1).reshape(-1)
+        ch_v = jnp.stack([v4, v4], axis=1).reshape(-1)
+        need = jnp.stack([found, found & ~is4], axis=1).reshape(-1)
+        a = _alloc_chords(inst, ex, ch_u, ch_v, need, with_aux=with_aux)
+        counts = None
+        if with_aux:
+            a, n_dropped = a
+            counts = SeparationCounts(
+                chords_added=jnp.sum(a.add_ok).astype(jnp.int32),
+                chords_dropped=n_dropped, long_rows=jnp.int32(0))
+        eid = a.eid.reshape(-1, 2)
+        ok = a.ok.reshape(-1, 2)
+        tri = _assemble_cycles45(v0, v4, b1, b2, b3, is4, found, lookup,
+                                 eid[:, 0], ok[:, 0], eid[:, 1], ok[:, 1])
+        csr2 = None
+        if splice_into is not None:
+            no_drop = jnp.zeros((inst.num_edges,), bool)
+            csr2 = splice_csr(splice_into, no_drop, a.add_u, a.add_v,
+                              a.add_eid, a.add_ok)
     return CycleSeparationResult(instance=a.instance, triangles=tri,
-                                 csr=csr2)
+                                 csr=csr2, counts=counts)
 
 
 def resolve_separation_shards(shards: int) -> int:
@@ -540,75 +584,81 @@ def _run_long_bucket(fn, consts, edge_args, is_long, chunk: int, shards: int,
     w_short/w_long so peak memory matches the short pass's per-chunk
     element budget. Sharded runs reuse the ungated streamer — the gate is a
     data-dependent skip that would break the static shard split; bit-
-    identity holds either way because only long lanes are ever read."""
+    identity holds either way because only long lanes are ever read.
+    Runs under the named scope ``repro.separation.long_bucket``."""
     M = edge_args[0].shape[0]
     base = chunk if chunk > 0 else M
     C = max(1, (base * w_short) // w_long)
-    if resolve_separation_shards(shards) > 1:
-        return _map_repulsive_batches(fn, consts, edge_args, C, shards)
-    return _map_long_chunks(fn, consts, edge_args, is_long, C)
+    with jax.named_scope("repro.separation.long_bucket"):
+        if resolve_separation_shards(shards) > 1:
+            return _map_repulsive_batches(fn, consts, edge_args, C, shards)
+        return _map_long_chunks(fn, consts, edge_args, is_long, C)
 
 
 def separate_cycles45(inst: MulticutInstance, adj: DenseAdj, max_neg: int,
-                      nbr_k: int = 4,
-                      node_mask=None) -> CycleSeparationResult:
+                      nbr_k: int = 4, node_mask=None,
+                      with_aux: bool = False) -> CycleSeparationResult:
     """4/5-cycles per Alg. 5, dense path: for repulsive edge (v0, v4), scan
     pairs (v1, v3) ∈ N⁺(v0) × N⁺(v4); a 4-cycle needs v1v3 ∈ E⁺, a 5-cycle a
     common attractive neighbour v2 (via the A⁺A⁺ matmul). The best pair per
-    repulsive edge is triangulated with zero-cost chords."""
-    N = inst.num_nodes
-    nbr_k = min(nbr_k, N)
-    # (bf16 rows were tried here and measured 3% WORSE — the convert op
-    # costs more than the halved gather at nbr_k=4; §Perf cell C iter 3)
-    Aposf = adj.Apos.astype(jnp.float32)
-    # 2-path existence is only needed for the (v1, v3) candidate pairs of
-    # the selected repulsive edges — max_neg·nbr_k² pairs. The full P2 =
-    # A⁺A⁺ product costs 2N³ FLOPs (137 GF at the pd_round_lg shape); the
-    # per-edge row-dot form below costs 2·max_neg·nbr_k²·N (34 MF, 4000x
-    # less) with identical results. EXPERIMENTS.md §Perf cell C iter 1.
-    neg_idx, neg_ok = select_repulsive_edges(inst, max_neg,
-                                             node_mask=node_mask)
-    v0 = inst.u[neg_idx]
-    v4 = inst.v[neg_idx]
+    repulsive edge is triangulated with zero-cost chords. Runs under the
+    named scope ``repro.separation.cycles45``; ``with_aux`` (static) fills
+    the result's ``counts``."""
+    with jax.named_scope("repro.separation.cycles45"):
+        N = inst.num_nodes
+        nbr_k = min(nbr_k, N)
+        # (bf16 rows were tried here and measured 3% WORSE — the convert op
+        # costs more than the halved gather at nbr_k=4; §Perf cell C iter 3)
+        Aposf = adj.Apos.astype(jnp.float32)
+        # 2-path existence is only needed for the (v1, v3) candidate pairs of
+        # the selected repulsive edges — max_neg·nbr_k² pairs. The full P2 =
+        # A⁺A⁺ product costs 2N³ FLOPs (137 GF at the pd_round_lg shape); the
+        # per-edge row-dot form below costs 2·max_neg·nbr_k²·N (34 MF, 4000x
+        # less) with identical results. EXPERIMENTS.md §Perf cell C iter 1.
+        neg_idx, neg_ok = select_repulsive_edges(inst, max_neg,
+                                                 node_mask=node_mask)
+        v0 = inst.u[neg_idx]
+        v4 = inst.v[neg_idx]
 
-    def per_edge(v0_, v4_, ok_):
-        w0, n0 = jax.lax.top_k(Aposf[v0_], nbr_k)     # neighbours of v0
-        w4, n4 = jax.lax.top_k(Aposf[v4_], nbr_k)     # neighbours of v4
-        ok0 = w0 > 0
-        ok4 = w4 > 0
-        pair_ok = ok0[:, None] & ok4[None, :] & ok_
-        v1 = jnp.broadcast_to(n0[:, None], (nbr_k, nbr_k))
-        v3 = jnp.broadcast_to(n4[None, :], (nbr_k, nbr_k))
-        distinct = (v1 != v3) & (v1 != v4_) & (v3 != v0_)
-        is4 = pair_ok & distinct & adj.Apos[v1, v3]
-        # (nbr_k, N) @ (N, nbr_k) batched row-dot == P2[v1, v3]
-        pair_counts = Aposf[n0] @ Aposf[n4].T
-        has2path = pair_counts > 0
-        is5 = pair_ok & distinct & ~is4 & has2path
-        # score: prefer 4-cycles, strongest attractive support
-        score = jnp.where(is4, 2.0, jnp.where(is5, 1.0, -jnp.inf)) \
-            + jnp.minimum(w0[:, None], w4[None, :]) * 1e-3
-        flat = jnp.argmax(score)
-        bi, bj = flat // nbr_k, flat % nbr_k
-        found = score.reshape(-1)[flat] > -jnp.inf
-        b_v1 = v1[bi, bj]
-        b_v3 = v3[bi, bj]
-        b_is4 = is4[bi, bj]
-        # for the 5-cycle pick v2 = common attractive neighbour of v1, v3
-        common = (adj.Apos[b_v1] & adj.Apos[b_v3]).astype(jnp.float32)
-        common = common.at[v0_].set(0.0).at[v4_].set(0.0)
-        b_v2 = jnp.argmax(common).astype(jnp.int32)
-        has_v2 = common[b_v2] > 0
-        found = found & (b_is4 | has_v2)
-        return (b_v1.astype(jnp.int32), b_v2, b_v3.astype(jnp.int32),
-                b_is4, found)
+        def per_edge(v0_, v4_, ok_):
+            w0, n0 = jax.lax.top_k(Aposf[v0_], nbr_k)     # neighbours of v0
+            w4, n4 = jax.lax.top_k(Aposf[v4_], nbr_k)     # neighbours of v4
+            ok0 = w0 > 0
+            ok4 = w4 > 0
+            pair_ok = ok0[:, None] & ok4[None, :] & ok_
+            v1 = jnp.broadcast_to(n0[:, None], (nbr_k, nbr_k))
+            v3 = jnp.broadcast_to(n4[None, :], (nbr_k, nbr_k))
+            distinct = (v1 != v3) & (v1 != v4_) & (v3 != v0_)
+            is4 = pair_ok & distinct & adj.Apos[v1, v3]
+            # (nbr_k, N) @ (N, nbr_k) batched row-dot == P2[v1, v3]
+            pair_counts = Aposf[n0] @ Aposf[n4].T
+            has2path = pair_counts > 0
+            is5 = pair_ok & distinct & ~is4 & has2path
+            # score: prefer 4-cycles, strongest attractive support
+            score = jnp.where(is4, 2.0, jnp.where(is5, 1.0, -jnp.inf)) \
+                + jnp.minimum(w0[:, None], w4[None, :]) * 1e-3
+            flat = jnp.argmax(score)
+            bi, bj = flat // nbr_k, flat % nbr_k
+            found = score.reshape(-1)[flat] > -jnp.inf
+            b_v1 = v1[bi, bj]
+            b_v3 = v3[bi, bj]
+            b_is4 = is4[bi, bj]
+            # for the 5-cycle pick v2 = common attractive neighbour of v1, v3
+            common = (adj.Apos[b_v1] & adj.Apos[b_v3]).astype(jnp.float32)
+            common = common.at[v0_].set(0.0).at[v4_].set(0.0)
+            b_v2 = jnp.argmax(common).astype(jnp.int32)
+            has_v2 = common[b_v2] > 0
+            found = found & (b_is4 | has_v2)
+            return (b_v1.astype(jnp.int32), b_v2, b_v3.astype(jnp.int32),
+                    b_is4, found)
 
-    b1, b2, b3, is4, found = jax.vmap(per_edge)(v0, v4, neg_ok)
+        b1, b2, b3, is4, found = jax.vmap(per_edge)(v0, v4, neg_ok)
 
-    # chords: 4-cycle v0-v1-v3-v4 needs chord (v1, v4);
-    #         5-cycle v0-v1-v2-v3-v4 needs chords (v1, v4) and (v2, v4)
-    return _alloc_and_assemble(inst, lambda a, b: adj.eidx[a, b],
-                               v0, v4, b1, b2, b3, is4, found)
+        # chords: 4-cycle v0-v1-v3-v4 needs chord (v1, v4);
+        #         5-cycle v0-v1-v2-v3-v4 needs chords (v1, v4) and (v2, v4)
+        return _alloc_and_assemble(inst, lambda a, b: adj.eidx[a, b],
+                                   v0, v4, b1, b2, b3, is4, found,
+                                   with_aux=with_aux)
 
 
 def separate_cycles45_sparse(inst: MulticutInstance, csr_pos: CsrGraph,
@@ -618,6 +668,7 @@ def separate_cycles45_sparse(inst: MulticutInstance, csr_pos: CsrGraph,
                              chunk: int = 0, shards: int = 1,
                              node_mask=None,
                              splice_into: CsrGraph | None = None,
+                             with_aux: bool = False,
                              ) -> CycleSeparationResult:
     """4/5-cycles, CSR path. Mirrors the dense scan pair for pair:
 
@@ -638,111 +689,124 @@ def separate_cycles45_sparse(inst: MulticutInstance, csr_pos: CsrGraph,
     computed once, up front, and decide each edge's bucket); chord
     allocation + triangulation run on the gathered winners in canonical
     order. ``splice_into`` maintains the caller's all-edges CSR through
-    chord allocation (see :func:`_alloc_and_assemble`).
+    chord allocation (see :func:`_alloc_and_assemble`). Runs under the
+    named scope ``repro.separation.cycles45``; ``with_aux`` (static) fills
+    the result's ``counts``, ``long_rows`` among them.
     """
     if intersect is None:
         intersect = intersect_rows_ref
-    N = inst.num_nodes
-    nbr_k = min(nbr_k, N)
-    W = max(1, min(row_cap, N))
-    Ws = max(1, min(row_cap_short, N)) if row_cap_short > 0 else W
-    neg_idx, neg_ok = select_repulsive_edges(inst, max_neg,
-                                             node_mask=node_mask)
-    v0 = inst.u[neg_idx]
-    v4 = inst.v[neg_idx]
-    fan = jax.vmap(lambda n: csr_row_window(csr_pos, n, nbr_k))
-    n0, _, ok0 = fan(v0)                            # (M, nbr_k)
-    n4, _, ok4 = fan(v4)
+    with jax.named_scope("repro.separation.cycles45"):
+        N = inst.num_nodes
+        nbr_k = min(nbr_k, N)
+        W = max(1, min(row_cap, N))
+        Ws = max(1, min(row_cap_short, N)) if row_cap_short > 0 else W
+        neg_idx, neg_ok = select_repulsive_edges(inst, max_neg,
+                                                 node_mask=node_mask)
+        v0 = inst.u[neg_idx]
+        v4 = inst.v[neg_idx]
+        fan = jax.vmap(lambda n: csr_row_window(csr_pos, n, nbr_k))
+        n0, _, ok0 = fan(v0)                            # (M, nbr_k)
+        n4, _, ok4 = fan(v4)
 
-    def make_candidates(Wb):
-        def candidates(csr_pos, v0_, v4_, n0_, n4_, ok0_, ok4_, ok_):
-            B = v0_.shape[0]
-            # windows of every fan node's attractive row: (B, nbr_k, Wb)
-            window = jax.vmap(jax.vmap(
-                lambda n: csr_row_window(csr_pos, n, Wb)))
-            r1c, _, r1ok = window(n0_)
-            r3c, _, _ = window(n4_)
+        def make_candidates(Wb):
+            def candidates(csr_pos, v0_, v4_, n0_, n4_, ok0_, ok4_, ok_):
+                B = v0_.shape[0]
+                # windows of every fan node's attractive row: (B, nbr_k, Wb)
+                window = jax.vmap(jax.vmap(
+                    lambda n: csr_row_window(csr_pos, n, Wb)))
+                r1c, _, r1ok = window(n0_)
+                r3c, _, _ = window(n4_)
 
-            # 2-path existence for every (v1, v3) pair, looped over the j
-            # fan so only (B·nbr_k, Wb) windows are live at once; only the
-            # boolean (B, nbr_k, nbr_k) result is kept
-            ci_flat = r1c.reshape(B * nbr_k, Wb)
-            oki_flat = r1ok.reshape(B * nbr_k, Wb)
-            has2 = []
-            for j in range(nbr_k):
-                cj_j = jnp.broadcast_to(r3c[:, None, j, :], (B, nbr_k, Wb)) \
-                    .reshape(B * nbr_k, Wb)
-                pos_j = intersect(ci_flat, cj_j)
-                has2.append(jnp.any((pos_j >= 0) & oki_flat, axis=-1)
-                            .reshape(B, nbr_k))
-            has2path = jnp.stack(has2, axis=-1)         # (B, nbr_k, nbr_k)
+                # 2-path existence for every (v1, v3) pair, looped over the j
+                # fan so only (B·nbr_k, Wb) windows are live at once; only the
+                # boolean (B, nbr_k, nbr_k) result is kept
+                ci_flat = r1c.reshape(B * nbr_k, Wb)
+                oki_flat = r1ok.reshape(B * nbr_k, Wb)
+                has2 = []
+                for j in range(nbr_k):
+                    cj_j = jnp.broadcast_to(r3c[:, None, j, :],
+                                            (B, nbr_k, Wb)) \
+                        .reshape(B * nbr_k, Wb)
+                    pos_j = intersect(ci_flat, cj_j)
+                    has2.append(jnp.any((pos_j >= 0) & oki_flat, axis=-1)
+                                .reshape(B, nbr_k))
+                has2path = jnp.stack(has2, axis=-1)         # (B, nbr_k, nbr_k)
 
-            v1 = jnp.broadcast_to(n0_[:, :, None], (B, nbr_k, nbr_k))
-            v3 = jnp.broadcast_to(n4_[:, None, :], (B, nbr_k, nbr_k))
-            # v1v3 ∈ E⁺ ⇔ v3 appears in v1's window: intersect the v4-fan
-            # (each row i of edge b asks for all of n4[b]) against r1c —
-            # invalid fan slots carry the N sentinel on both sides and are
-            # masked by pair_ok below
-            fan3 = jnp.broadcast_to(n4_[:, None, :], (B, nbr_k, nbr_k)) \
-                .reshape(B * nbr_k, nbr_k)
-            e13pos = intersect(fan3, ci_flat).reshape(B, nbr_k, nbr_k)
+                v1 = jnp.broadcast_to(n0_[:, :, None], (B, nbr_k, nbr_k))
+                v3 = jnp.broadcast_to(n4_[:, None, :], (B, nbr_k, nbr_k))
+                # v1v3 ∈ E⁺ ⇔ v3 appears in v1's window: intersect the v4-fan
+                # (each row i of edge b asks for all of n4[b]) against r1c —
+                # invalid fan slots carry the N sentinel on both sides and are
+                # masked by pair_ok below
+                fan3 = jnp.broadcast_to(n4_[:, None, :], (B, nbr_k, nbr_k)) \
+                    .reshape(B * nbr_k, nbr_k)
+                e13pos = intersect(fan3, ci_flat).reshape(B, nbr_k, nbr_k)
 
-            pair_ok = ok0_[:, :, None] & ok4_[:, None, :] & ok_[:, None, None]
-            distinct = (v1 != v3) & (v1 != v4_[:, None, None]) & \
-                (v3 != v0_[:, None, None])
-            is4 = pair_ok & distinct & (e13pos >= 0)
-            is5 = pair_ok & distinct & ~is4 & has2path
-            w0 = ok0_.astype(jnp.float32)
-            w4 = ok4_.astype(jnp.float32)
-            score = jnp.where(is4, 2.0, jnp.where(is5, 1.0, -jnp.inf)) \
-                + jnp.minimum(w0[:, :, None], w4[:, None, :]) * 1e-3
-            flat = jnp.argmax(score.reshape(B, -1), axis=1)
-            bi, bj = flat // nbr_k, flat % nbr_k
-            m = jnp.arange(B)
-            found = score.reshape(B, -1)[m, flat] > -jnp.inf
-            b1 = n0_[m, bi]
-            b3 = n4_[m, bj]
-            b_is4 = is4[m, bi, bj]
-            # v2 = smallest common attractive neighbour of (b1, b3),
-            # excluding the repulsive endpoints — first surviving element
-            # of the winning pair's (ascending) intersection, == dense
-            # argmax over the 0/1 common row. Re-intersect just the winning
-            # pair per repulsive edge ((B, Wb), cheap) instead of keeping
-            # the full pair batch alive.
-            win_cols = r1c[m, bi]                               # (B, Wb)
-            win_pos = intersect(win_cols, r3c[m, bj])
-            win_common = (win_pos >= 0) & r1ok[m, bi] & \
-                (win_cols != v0_[:, None]) & (win_cols != v4_[:, None])
-            has_v2 = jnp.any(win_common, axis=1)
-            first = jnp.argmax(win_common, axis=1)
-            b2 = jnp.where(has_v2, win_cols[m, first], 0).astype(jnp.int32)
-            found = found & (b_is4 | has_v2)
-            return (b1.astype(jnp.int32), b2, b3.astype(jnp.int32), b_is4,
-                    found)
-        return candidates
+                pair_ok = ok0_[:, :, None] & ok4_[:, None, :] \
+                    & ok_[:, None, None]
+                distinct = (v1 != v3) & (v1 != v4_[:, None, None]) & \
+                    (v3 != v0_[:, None, None])
+                is4 = pair_ok & distinct & (e13pos >= 0)
+                is5 = pair_ok & distinct & ~is4 & has2path
+                w0 = ok0_.astype(jnp.float32)
+                w4 = ok4_.astype(jnp.float32)
+                score = jnp.where(is4, 2.0, jnp.where(is5, 1.0, -jnp.inf)) \
+                    + jnp.minimum(w0[:, :, None], w4[:, None, :]) * 1e-3
+                flat = jnp.argmax(score.reshape(B, -1), axis=1)
+                bi, bj = flat // nbr_k, flat % nbr_k
+                m = jnp.arange(B)
+                found = score.reshape(B, -1)[m, flat] > -jnp.inf
+                b1 = n0_[m, bi]
+                b3 = n4_[m, bj]
+                b_is4 = is4[m, bi, bj]
+                # v2 = smallest common attractive neighbour of (b1, b3),
+                # excluding the repulsive endpoints — first surviving element
+                # of the winning pair's (ascending) intersection, == dense
+                # argmax over the 0/1 common row. Re-intersect just the winning
+                # pair per repulsive edge ((B, Wb), cheap) instead of keeping
+                # the full pair batch alive.
+                win_cols = r1c[m, bi]                               # (B, Wb)
+                win_pos = intersect(win_cols, r3c[m, bj])
+                win_common = (win_pos >= 0) & r1ok[m, bi] & \
+                    (win_cols != v0_[:, None]) & (win_cols != v4_[:, None])
+                has_v2 = jnp.any(win_common, axis=1)
+                first = jnp.argmax(win_common, axis=1)
+                b2 = jnp.where(has_v2, win_cols[m, first], 0).astype(jnp.int32)
+                found = found & (b_is4 | has_v2)
+                return (b1.astype(jnp.int32), b2, b3.astype(jnp.int32), b_is4,
+                        found)
+            return candidates
 
-    if Ws >= W:
-        b1, b2, b3, is4, found = _map_repulsive_batches(
-            make_candidates(W), csr_pos,
-            (v0, v4, n0, n4, ok0, ok4, neg_ok), chunk, shards)
-    else:
-        # an edge is long iff ANY window it reads (its fan nodes' rows)
-        # overflows the short cap
-        deg = csr_pos.degrees
-        dl0 = jnp.where(ok0, deg[jnp.clip(n0, 0, N - 1)], 0)
-        dl4 = jnp.where(ok4, deg[jnp.clip(n4, 0, N - 1)], 0)
-        is_long = (jnp.max(dl0, axis=1) > Ws) | (jnp.max(dl4, axis=1) > Ws)
-        out_s = _map_repulsive_batches(
-            make_candidates(Ws), csr_pos,
-            (v0, v4, n0, n4, ok0, ok4, neg_ok & ~is_long), chunk, shards)
-        out_l = _run_long_bucket(
-            make_candidates(W), csr_pos,
-            (v0, v4, n0, n4, ok0, ok4, neg_ok & is_long),
-            is_long, chunk, shards, Ws, W)
-        b1, b2, b3, is4, found = _combine_buckets(is_long, out_s, out_l)
-    lookup_all = jax.vmap(lambda a, b: csr_lookup_edge(csr_all, a, b))
-    return _alloc_and_assemble(inst, lookup_all, v0, v4, b1, b2, b3, is4,
-                               found, splice_into=splice_into)
+        if Ws >= W:
+            b1, b2, b3, is4, found = _map_repulsive_batches(
+                make_candidates(W), csr_pos,
+                (v0, v4, n0, n4, ok0, ok4, neg_ok), chunk, shards)
+            if with_aux:
+                n_long = jnp.int32(0)
+        else:
+            # an edge is long iff ANY window it reads (its fan nodes' rows)
+            # overflows the short cap
+            deg = csr_pos.degrees
+            dl0 = jnp.where(ok0, deg[jnp.clip(n0, 0, N - 1)], 0)
+            dl4 = jnp.where(ok4, deg[jnp.clip(n4, 0, N - 1)], 0)
+            is_long = (jnp.max(dl0, axis=1) > Ws) | (jnp.max(dl4, axis=1) > Ws)
+            out_s = _map_repulsive_batches(
+                make_candidates(Ws), csr_pos,
+                (v0, v4, n0, n4, ok0, ok4, neg_ok & ~is_long), chunk, shards)
+            out_l = _run_long_bucket(
+                make_candidates(W), csr_pos,
+                (v0, v4, n0, n4, ok0, ok4, neg_ok & is_long),
+                is_long, chunk, shards, Ws, W)
+            b1, b2, b3, is4, found = _combine_buckets(is_long, out_s, out_l)
+            if with_aux:
+                n_long = jnp.sum(neg_ok & is_long).astype(jnp.int32)
+        lookup_all = jax.vmap(lambda a, b: csr_lookup_edge(csr_all, a, b))
+        res = _alloc_and_assemble(inst, lookup_all, v0, v4, b1, b2, b3, is4,
+                                  found, splice_into=splice_into,
+                                  with_aux=with_aux)
+    if with_aux:
+        res = res._replace(counts=res.counts._replace(long_rows=n_long))
+    return res
 
 
 # ---------------------------------------------------------------------------
@@ -757,7 +821,8 @@ def separate(inst: MulticutInstance, max_neg: int, max_tri_per_edge: int,
              csr: CsrGraph | None = None, separation_chunk: int = 0,
              separation_shards: int = 1,
              sep_node_mask=None,
-             update_csr: bool = False) -> CycleSeparationResult:
+             update_csr: bool = False,
+             with_aux: bool = False) -> CycleSeparationResult:
     """Full separation round: 3-cycles always; 4/5-cycles optionally
     (PD uses 5 on the original graph, 3 on contracted graphs; PD+ always 5).
 
@@ -786,16 +851,26 @@ def separate(inst: MulticutInstance, max_neg: int, max_tri_per_edge: int,
     loop can carry it — requested explicitly because eager callers would
     otherwise pay the splice for an output they drop (jit DCE removes it
     for free, eager does not).
+
+    ``with_aux`` (static) fills the result's ``counts``
+    (:class:`SeparationCounts`); off by default, leaving the jaxpr
+    unchanged. Its parts run under the named scopes
+    ``repro.separation.<part>``: ``select``, ``triangles``,
+    ``long_bucket``, ``cycles45`` and ``chords``.
     """
     impl = resolve_graph_impl(graph_impl, inst.num_nodes, sparse_threshold)
     if impl == "dense":
         adj = build_adjacency(inst)
         tri3 = separate_triangles(inst, adj, max_neg, max_tri_per_edge,
                                   node_mask=sep_node_mask)
+        if with_aux:
+            tri3_long = jnp.int32(0)
         if not with_cycles45:
-            return CycleSeparationResult(instance=inst, triangles=tri3)
+            return CycleSeparationResult(
+                instance=inst, triangles=tri3,
+                counts=_no_chords(tri3_long) if with_aux else None)
         res45 = separate_cycles45(inst, adj, max_neg, nbr_k=nbr_k,
-                                  node_mask=sep_node_mask)
+                                  node_mask=sep_node_mask, with_aux=with_aux)
     else:
         csr_all = csr_from_instance(inst) if csr is None else csr
         csr_pos = csr_filter(csr_all, inst.edge_valid & (inst.cost > 0))
@@ -806,11 +881,15 @@ def separate(inst: MulticutInstance, max_neg: int, max_tri_per_edge: int,
                                          intersect=intersect,
                                          chunk=separation_chunk,
                                          shards=separation_shards,
-                                         node_mask=sep_node_mask)
+                                         node_mask=sep_node_mask,
+                                         with_aux=with_aux)
+        if with_aux:
+            tri3, tri3_long = tri3
         if not with_cycles45:
             return CycleSeparationResult(
                 instance=inst, triangles=tri3,
-                csr=csr_all if update_csr else None)
+                csr=csr_all if update_csr else None,
+                counts=_no_chords(tri3_long) if with_aux else None)
         res45 = separate_cycles45_sparse(inst, csr_pos, csr_all, max_neg,
                                          nbr_k=nbr_k,
                                          row_cap=sparse_row_cap,
@@ -820,10 +899,15 @@ def separate(inst: MulticutInstance, max_neg: int, max_tri_per_edge: int,
                                          shards=separation_shards,
                                          node_mask=sep_node_mask,
                                          splice_into=(csr_all if update_csr
-                                                      else None))
+                                                      else None),
+                                         with_aux=with_aux)
     edges = jnp.concatenate([tri3.edges, res45.triangles.edges], axis=0)
     valid = jnp.concatenate([tri3.valid, res45.triangles.valid], axis=0)
+    counts = None
+    if with_aux:
+        counts = res45.counts._replace(
+            long_rows=res45.counts.long_rows + tri3_long)
     return CycleSeparationResult(
         instance=res45.instance,
         triangles=Triangles(edges=edges, valid=valid),
-        csr=res45.csr)
+        csr=res45.csr, counts=counts)

@@ -205,11 +205,13 @@ def _dual_round_core(inst: MulticutInstance, cfg: SolverConfig,
     (inst', c_rep, lb, csr') — ``csr'`` is the chord-spliced all-edges CSR
     when ``update_csr`` (sparse path), else None.
 
-    ``with_aux`` (static) appends a telemetry tuple ``(n_cycles,
-    mp_improvement)``: conflicted cycles found by separation, and the LB
+    ``with_aux`` (static) appends a telemetry dict of
+    :class:`repro.obs.trace.SolveTrace` row values: ``n_cycles``
+    (conflicted cycles found by separation), ``mp_improvement`` (the LB
     gain of the MP sweep over the trivial bound Σ_e min(0, c) on the
-    round's pre-MP costs. Off by default so untraced jaxprs are
-    byte-for-byte unchanged."""
+    round's pre-MP costs) and separation's ``chords_added``,
+    ``chords_dropped`` and ``long_rows``. Off by default so untraced
+    jaxprs are byte-for-byte unchanged."""
     with jax.named_scope("repro.separation"):
         sep = separate(inst, max_neg=cfg.max_neg,
                        max_tri_per_edge=cfg.max_tri_per_edge,
@@ -222,7 +224,7 @@ def _dual_round_core(inst: MulticutInstance, cfg: SolverConfig,
                        separation_chunk=cfg.separation_chunk,
                        separation_shards=cfg.separation_shards,
                        sep_node_mask=node_mask,
-                       update_csr=update_csr)
+                       update_csr=update_csr, with_aux=with_aux)
     inst2 = sep.instance
     with jax.named_scope("repro.message_passing"):
         state = init_mp(sep.triangles)
@@ -233,16 +235,28 @@ def _dual_round_core(inst: MulticutInstance, cfg: SolverConfig,
     n_cyc = jnp.sum(sep.triangles.valid).astype(jnp.int32)
     trivial_lb = tree_sum(jnp.where(inst2.edge_valid,
                                     jnp.minimum(0.0, inst2.cost), 0.0))
-    return inst2, c_rep, lb, sep.csr, (n_cyc, lb - trivial_lb)
+    aux = dict(n_cycles=n_cyc, mp_improvement=lb - trivial_lb,
+               **sep.counts._asdict())
+    return inst2, c_rep, lb, sep.csr, aux
 
 
-def _primal_round_core(inst: MulticutInstance, cfg: SolverConfig):
+def _primal_round_core(inst: MulticutInstance, cfg: SolverConfig,
+                       with_aux: bool = False):
+    """Choose the contraction set and contract it. Returns
+    (ContractionResult, CsrGraph) — plus, when ``with_aux``, a telemetry
+    dict: ``cc_iters`` (trips of every connected-components loop of the
+    round) and ``forest_chosen``."""
     with jax.named_scope("repro.contraction"):
-        S = choose_contraction_set(inst, matching_rounds=cfg.matching_rounds,
-                                   forest_rounds=cfg.forest_rounds,
-                                   switch_frac=cfg.switch_frac,
-                                   contract_frac=cfg.contract_frac)
-        return contract(inst, S)
+        chosen = choose_contraction_set(
+            inst, matching_rounds=cfg.matching_rounds,
+            forest_rounds=cfg.forest_rounds, switch_frac=cfg.switch_frac,
+            contract_frac=cfg.contract_frac, with_aux=with_aux)
+        if not with_aux:
+            return contract_csr(inst, chosen)
+        S, forest_iters, forest_chosen = chosen
+        res, csr, cc_iters = contract_csr(inst, S, with_aux=True)
+    return res, csr, dict(cc_iters=forest_iters + cc_iters,
+                          forest_chosen=forest_chosen)
 
 
 def _live_edges1(inst: MulticutInstance) -> jnp.ndarray:
@@ -255,15 +269,17 @@ def fused_pd_round(inst: MulticutInstance, cfg: SolverConfig,
                    with_aux: bool = False):
     """Alg. 3 lines 3–8 as one traceable unit: separation → message passing
     → reparametrize → contract. Returns (ContractionResult, lb) — plus the
-    telemetry aux of :func:`_dual_round_core` when ``with_aux``. Input and
-    output instances share shapes, so the outer while_loop carries it."""
+    telemetry of :func:`_dual_round_core` and :func:`_primal_round_core`
+    (one dict) when ``with_aux``. Input and output instances share shapes,
+    so the outer while_loop carries it."""
     out = _dual_round_core(inst, cfg, with45, sweep, intersect,
                            node_mask=node_mask, with_aux=with_aux)
     inst2, c_rep = out[0], out[1]
-    res = _primal_round_core(inst2._replace(cost=c_rep), cfg)
+    con = _primal_round_core(inst2._replace(cost=c_rep), cfg,
+                             with_aux=with_aux)
     if with_aux:
-        return res, out[2], out[4]
-    return res, out[2]
+        return con[0], out[2], {**out[4], **con[2]}
+    return con[0], out[2]
 
 
 def fused_pd_round_state(state: SolverState, cfg: SolverConfig, with45: bool,
@@ -272,22 +288,18 @@ def fused_pd_round_state(state: SolverState, cfg: SolverConfig, with45: bool,
     """The state-carrying PD round (sparse data path): separation reads the
     carried CSR (no rebuild), contraction maintains it, and the original→
     cluster mapping composes in place. Returns (SolverState', lb, res) —
-    plus the telemetry aux of :func:`_dual_round_core` when ``with_aux``."""
+    plus the telemetry dict of :func:`fused_pd_round` when ``with_aux``."""
     out = _dual_round_core(state.instance, cfg, with45, sweep, intersect,
                            csr=state.csr, node_mask=node_mask,
                            with_aux=with_aux)
     inst2, c_rep = out[0], out[1]
-    inst3 = inst2._replace(cost=c_rep)
-    with jax.named_scope("repro.contraction"):
-        S = choose_contraction_set(inst3, matching_rounds=cfg.matching_rounds,
-                                   forest_rounds=cfg.forest_rounds,
-                                   switch_frac=cfg.switch_frac,
-                                   contract_frac=cfg.contract_frac)
-        res, csr2 = contract_csr(inst3, S)
+    con = _primal_round_core(inst2._replace(cost=c_rep), cfg,
+                             with_aux=with_aux)
+    res, csr2 = con[0], con[1]
     state2 = SolverState(instance=res.instance, csr=csr2,
                          mapping=res.mapping[state.mapping])
     if with_aux:
-        return state2, out[2], res, out[4]
+        return state2, out[2], res, {**out[4], **con[2]}
     return state2, out[2], res
 
 
@@ -315,7 +327,8 @@ def _solve_p_device(inst: MulticutInstance, cfg: SolverConfig,
 
     def body(carry):
         r, cur, mapping, _, hist_nc, hist_nk = carry[:6]
-        res = _primal_round_core(cur, cfg)
+        con = _primal_round_core(cur, cfg, with_aux=trace)
+        res = con[0]
         nc = res.n_contracted.astype(jnp.int32)
         hist_nc = hist_nc.at[r].set(nc)
         hist_nk = hist_nk.at[r].set(res.n_new.astype(jnp.int32))
@@ -325,7 +338,7 @@ def _solve_p_device(inst: MulticutInstance, cfg: SolverConfig,
             tr = trace_set_round(
                 carry[6], r, objective=inst.objective(mapping2),
                 n_contracted=nc, n_clusters=res.n_new.astype(jnp.int32),
-                shard_edges=_live_edges1(res.instance))
+                shard_edges=_live_edges1(res.instance), **con[2])
             out = out + (tr,)
         return out
 
@@ -389,27 +402,21 @@ def _solve_pd_sparse(inst: MulticutInstance, cfg: SolverConfig, plus: bool,
         hist_nk = hist_nk.at[r].set(res.n_new.astype(jnp.int32))
         out = (r + 1, st2, nc, hist_lb, hist_nc, hist_nk)
         if trace:
-            n_cyc, mp_gain = rnd[3]
             tr = trace_set_round(
                 carry[6], r, lower_bound=lb,
                 objective=inst.objective(st2.mapping),
-                n_cycles=n_cyc, n_contracted=nc,
-                n_clusters=res.n_new.astype(jnp.int32),
-                mp_improvement=mp_gain,
-                shard_edges=_live_edges1(st2.instance))
+                n_contracted=nc, n_clusters=res.n_new.astype(jnp.int32),
+                shard_edges=_live_edges1(st2.instance), **rnd[3])
             out = out + (tr,)
         return out
 
     init = (jnp.int32(1), state, nc0, hist_lb, hist_nc, hist_nk)
     if trace:
-        n_cyc0, mp_gain0 = out0[3]
         tr0 = trace_set_round(
             init_trace(R), jnp.int32(0), lower_bound=lb0,
             objective=inst.objective(state.mapping),
-            n_cycles=n_cyc0, n_contracted=nc0,
-            n_clusters=res0.n_new.astype(jnp.int32),
-            mp_improvement=mp_gain0,
-            shard_edges=_live_edges1(state.instance))
+            n_contracted=nc0, n_clusters=res0.n_new.astype(jnp.int32),
+            shard_edges=_live_edges1(state.instance), **out0[3])
         init = init + (tr0,)
     out = jax.lax.while_loop(cond, body, init)
     r, state, hist_lb, hist_nc, hist_nk = \
@@ -486,28 +493,22 @@ def _solve_pd_device(inst: MulticutInstance, cfg: SolverConfig, plus: bool,
         out = (r + 1, res.instance, mapping2, nc,
                hist_lb, hist_nc, hist_nk)
         if trace:
-            n_cyc, mp_gain = rnd[2]
             tr = trace_set_round(
                 carry[7], r, lower_bound=lb,
                 objective=inst.objective(mapping2),
-                n_cycles=n_cyc, n_contracted=nc,
-                n_clusters=res.n_new.astype(jnp.int32),
-                mp_improvement=mp_gain,
-                shard_edges=_live_edges1(res.instance))
+                n_contracted=nc, n_clusters=res.n_new.astype(jnp.int32),
+                shard_edges=_live_edges1(res.instance), **rnd[2])
             out = out + (tr,)
         return out
 
     init = (jnp.int32(1), res0.instance, mapping, nc0,
             hist_lb, hist_nc, hist_nk)
     if trace:
-        n_cyc0, mp_gain0 = out0[2]
         tr0 = trace_set_round(
             init_trace(R), jnp.int32(0), lower_bound=lb0,
             objective=inst.objective(mapping),
-            n_cycles=n_cyc0, n_contracted=nc0,
-            n_clusters=res0.n_new.astype(jnp.int32),
-            mp_improvement=mp_gain0,
-            shard_edges=_live_edges1(res0.instance))
+            n_contracted=nc0, n_clusters=res0.n_new.astype(jnp.int32),
+            shard_edges=_live_edges1(res0.instance), **out0[2])
         init = init + (tr0,)
     out = jax.lax.while_loop(cond, body, init)
     r, mapping, hist_lb, hist_nc, hist_nk = \
@@ -555,7 +556,7 @@ def _solve_d_device(inst: MulticutInstance, cfg: SolverConfig, sweep=None,
                                    csr=csr, update_csr=True, with_aux=trace)
             cur2, c_rep, lb, csr2 = rnd[0], rnd[1], rnd[2], rnd[3]
             tri_lb_sum, total = lb_parts(cur2, c_rep, lb, tri_lb_sum)
-            ys = (total,) + (rnd[4] if trace else ())
+            ys = (total,) + ((rnd[4],) if trace else ())
             return (cur2._replace(cost=c_rep), csr2, tri_lb_sum), ys
 
         (final, _, _), ys = jax.lax.scan(
@@ -568,7 +569,7 @@ def _solve_d_device(inst: MulticutInstance, cfg: SolverConfig, sweep=None,
                                    with_aux=trace)
             cur2, c_rep, lb = rnd[0], rnd[1], rnd[2]
             tri_lb_sum, total = lb_parts(cur2, c_rep, lb, tri_lb_sum)
-            ys = (total,) + (rnd[4] if trace else ())
+            ys = (total,) + ((rnd[4],) if trace else ())
             return (cur2._replace(cost=c_rep), tri_lb_sum), ys
 
         (final, _), ys = jax.lax.scan(body, (inst, jnp.float32(0.0)),
@@ -584,15 +585,15 @@ def _solve_d_device(inst: MulticutInstance, cfg: SolverConfig, sweep=None,
                       n_clusters=jnp.broadcast_to(n_nodes, (R,)))
     tr = None
     if trace:
-        # D has no primal: objective rows stay padding; the stacked scan
-        # outputs land in the trace wholesale (no in-loop scatter needed)
-        n_cycs, mp_gains = ys[1], ys[2]
-        tr = init_trace(R)._replace(
+        # D has no primal: objective and contraction rows stay padding;
+        # the stacked scan outputs land in the trace wholesale (no in-loop
+        # scatter needed)
+        tr = init_trace(R)
+        tr = tr._replace(
             rounds=jnp.int32(R),
             lower_bound=per_round.astype(jnp.float32),
-            n_cycles=n_cycs.astype(jnp.int32),
-            mp_improvement=mp_gains.astype(jnp.float32),
             n_clusters=jnp.broadcast_to(n_nodes, (R,)),
+            **{k: v.astype(getattr(tr, k).dtype) for k, v in ys[1].items()},
             shard_edges=jnp.broadcast_to(
                 jnp.sum(inst.edge_valid).astype(jnp.int32), (R, 1)))
     return res, final, tr

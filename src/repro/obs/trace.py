@@ -23,7 +23,19 @@ Shape convention: per-round leaves are padded to ``(max_rounds,)`` (or
 chosen so :func:`summarize` can mask it out (``rounds`` says how many
 entries are live). The per-shard leaves have ``shards == 1`` on the
 unsharded paths so a trace always has the same treedef regardless of
-which solve path produced it.
+which solve path produced it. Likewise every path carries every leaf: a
+path that does not run the code a leaf counts leaves it as padding (0) —
+the separation counters in mode p, the contraction counters in mode d,
+``long_rows`` on the dense data path, and all five counters on the
+state-sharded solve.
+
+Named scopes: the solver's phases run under ``repro.<phase>``
+(``separation``, ``message_passing``, ``contraction``) and their parts
+under ``repro.<phase>.<part>``, nested inside the phase's scope, so a
+device profile's ``op_name`` metadata says which part an op belongs to
+(innermost part wins): ``repro.contraction.{matching, forest,
+components, merge, csr}`` and ``repro.separation.{select, triangles,
+long_bucket, cycles45, chords}``.
 
 :func:`summarize` is the opt-in host-side view — it is the ONLY place
 that calls ``float()``/``int()`` on trace leaves, keeping every sync off
@@ -36,6 +48,11 @@ from typing import NamedTuple
 import jax.numpy as jnp
 
 __all__ = ["SolveTrace", "init_trace", "trace_set_round", "summarize"]
+
+# Per-round i32 counters of the contraction and separation parts, padded
+# with 0; summarize() reports each one's total over the solve's rounds.
+COUNTERS = ("cc_iters", "forest_chosen", "chords_added", "chords_dropped",
+            "long_rows")
 
 # Padding sentinels (masked out by `summarize` via `rounds`): +inf for
 # minimised quantities keeps best-so-far scans monotone; -inf for the LB.
@@ -57,6 +74,18 @@ class SolveTrace(NamedTuple):
     - ``n_clusters``: (R,) i32 — clusters remaining after the round.
     - ``mp_improvement``: (R,) f32 — LB gain of the MP sweep over the
       trivial bound Σ min(0, cost) on the round's reparametrized costs.
+    - ``cc_iters``: (R,) i32 — while-loop trips of every
+      connected-components call in the round's contraction: the
+      forest's ``2 * forest_rounds`` calls plus the contraction's one.
+    - ``forest_chosen``: (R,) i32 — 1 where the round contracted the
+      spanning forest's set rather than the matching's.
+    - ``chords_added``: (R,) i32 — fresh 4/5-cycle chords written into
+      a free edge slot.
+    - ``chords_dropped``: (R,) i32 — fresh chords that found no free
+      slot (their cycles are not separated; an instance padded with no
+      free slots drops them all).
+    - ``long_rows``: (R,) i32 — repulsive edges that separation ran in
+      the long degree bucket (3- and 4/5-cycle searches together).
     - ``shard_edges``: (R, S) i32 — live (valid) edges owned per shard.
     - ``shard_topk``: (R, S) i32 — repulsive-anchor slots won per shard
       in the global top-k (top-k imbalance: one shard hogging anchors
@@ -72,6 +101,11 @@ class SolveTrace(NamedTuple):
     n_contracted: jnp.ndarray
     n_clusters: jnp.ndarray
     mp_improvement: jnp.ndarray
+    cc_iters: jnp.ndarray
+    forest_chosen: jnp.ndarray
+    chords_added: jnp.ndarray
+    chords_dropped: jnp.ndarray
+    long_rows: jnp.ndarray
     shard_edges: jnp.ndarray
     shard_topk: jnp.ndarray
     shard_halo: jnp.ndarray
@@ -91,6 +125,11 @@ def init_trace(max_rounds: int, shards: int = 1) -> SolveTrace:
         n_contracted=jnp.zeros((r,), i),
         n_clusters=jnp.zeros((r,), i),
         mp_improvement=jnp.zeros((r,), f),
+        cc_iters=jnp.zeros((r,), i),
+        forest_chosen=jnp.zeros((r,), i),
+        chords_added=jnp.zeros((r,), i),
+        chords_dropped=jnp.zeros((r,), i),
+        long_rows=jnp.zeros((r,), i),
         shard_edges=jnp.zeros((r, s), i),
         shard_topk=jnp.zeros((r, s), i),
         shard_halo=jnp.zeros((r, s), i),
@@ -99,7 +138,9 @@ def init_trace(max_rounds: int, shards: int = 1) -> SolveTrace:
 
 def trace_set_round(trace: SolveTrace, r, *, lower_bound=None,
                     objective=None, n_cycles=None, n_contracted=None,
-                    n_clusters=None, mp_improvement=None, shard_edges=None,
+                    n_clusters=None, mp_improvement=None, cc_iters=None,
+                    forest_chosen=None, chords_added=None,
+                    chords_dropped=None, long_rows=None, shard_edges=None,
                     shard_topk=None, shard_halo=None) -> SolveTrace:
     """Write row ``r`` (a traced i32 scalar) of the per-round leaves and
     bump ``rounds``. Fields left as None keep their padding — the dual
@@ -108,7 +149,9 @@ def trace_set_round(trace: SolveTrace, r, *, lower_bound=None,
     updates = dict(lower_bound=lower_bound, objective=objective,
                    n_cycles=n_cycles, n_contracted=n_contracted,
                    n_clusters=n_clusters, mp_improvement=mp_improvement,
-                   shard_edges=shard_edges, shard_topk=shard_topk,
+                   cc_iters=cc_iters, forest_chosen=forest_chosen,
+                   chords_added=chords_added, chords_dropped=chords_dropped,
+                   long_rows=long_rows, shard_edges=shard_edges, shard_topk=shard_topk,
                    shard_halo=shard_halo)
     out = {}
     for name, val in updates.items():
@@ -138,6 +181,8 @@ def _rows(trace: SolveTrace) -> list[dict]:
             "n_clusters": int(trace.n_clusters[r]),
             "mp_improvement": float(trace.mp_improvement[r]),
         }
+        row.update({name: int(getattr(trace, name)[r])
+                    for name in COUNTERS})
         if shards > 1:
             row["shard_edges"] = [int(x) for x in trace.shard_edges[r]]
             row["shard_topk"] = [int(x) for x in trace.shard_topk[r]]
@@ -156,7 +201,10 @@ def _imbalance(per_shard: list[int]) -> float:
 
 def summarize(trace: SolveTrace) -> dict:
     """Host-side digest of a trace: per-round rows, convergence
-    trajectory (first/best/final LB + objective, duality gap), and —
+    trajectory (first/best/final LB + objective, duality gap), the
+    per-solve totals of the contraction and separation counters
+    (``total_cc_iters``, ``total_forest_chosen``, ``total_chords_added``,
+    ``total_chords_dropped``, ``total_long_rows``), and —
     for sharded solves — per-round imbalance ratios for edges / top-k
     anchors / halo pressure. This is the ONLY trace consumer that pulls
     device values to the host; call it off the hot path."""
@@ -179,6 +227,8 @@ def summarize(trace: SolveTrace) -> dict:
         out["gap"] = finite_obj[-1] - max(lbs)
     out["total_contracted"] = sum(r["n_contracted"] for r in rows)
     out["total_cycles"] = sum(r["n_cycles"] for r in rows)
+    for name in COUNTERS:
+        out[f"total_{name}"] = sum(r[name] for r in rows)
 
     shards = int(trace.shard_edges.shape[-1])
     if shards > 1:

@@ -9,6 +9,7 @@ separate registry entries so flipping the flag never recompiles the
 other. :func:`repro.obs.summarize` is the only host-side consumer.
 """
 import dataclasses
+import functools
 import math
 import os
 import subprocess
@@ -20,8 +21,14 @@ import numpy as np
 import pytest
 
 from repro import api
-from repro.core.graph import random_instance
-from repro.core.solver import MODES, SolverConfig, solve_device
+from repro.core.contraction import (
+    connected_components, maximum_matching, spanning_forest_contraction,
+)
+from repro.core.cycles import separate
+from repro.core.graph import grid_instance, random_instance
+from repro.core.solver import (
+    MODES, SolverConfig, _solve_d_device, solve_device,
+)
 from repro.obs import SolveTrace, init_trace, summarize, trace_set_round
 
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
@@ -218,6 +225,11 @@ def test_traced_sharded_solve_bitwise_across_shard_counts():
                 assert np.asarray(x).tobytes() == np.asarray(y).tobytes(), S
             R = int(tr.rounds)
             assert R >= 1 and tr.shard_edges.shape[1] == S, S
+            # the part counters are not threaded through the sharded
+            # solve: padding on every row
+            for name in ("cc_iters", "forest_chosen", "chords_added",
+                         "chords_dropped", "long_rows"):
+                assert not np.any(np.asarray(getattr(tr, name))), name
             totals[S] = np.asarray(tr.shard_edges)[:R].sum(axis=1)
             s = summarize(tr)
             if S > 1:
@@ -232,3 +244,183 @@ def test_traced_sharded_solve_bitwise_across_shard_counts():
         print("traced-sharded-ok")
         """)
     assert "traced-sharded-ok" in stdout
+
+
+# ---------------------------------------------------------------------------
+# contraction and separation counters
+# ---------------------------------------------------------------------------
+
+# 12 x 12 grid: 537 edges; padded to 1024 it has 487 free slots for chords,
+# unpadded none. Sparse with a short cap of 4, so the long bucket has rows.
+GRID_CFG = SolverConfig(graph_impl="sparse", sparse_row_cap=16,
+                        sparse_row_cap_short=4)
+SEP_COUNTERS = ("chords_added", "chords_dropped", "long_rows")
+CON_COUNTERS = ("cc_iters", "forest_chosen")
+
+
+def _grid(pad):
+    return grid_instance(12, 12, seed=1, pad_edges=pad)
+
+
+@functools.lru_cache(maxsize=None)
+def _grid_solve(pad, mode, impl="sparse"):
+    """(untraced result, traced result, trace) of the 12 x 12 grid."""
+    cfg = dataclasses.replace(GRID_CFG, graph_impl=impl)
+    inst = _grid(pad)
+    ref = api.solve(inst, mode=mode, config=cfg)
+    res, tr = api.solve(inst, mode=mode, config=cfg, trace=True)
+    return ref, res, tr
+
+
+def _live(tr, name):
+    return np.asarray(getattr(tr, name))[:int(tr.rounds)]
+
+
+@pytest.mark.parametrize("pad", [None, 1024], ids=["unpadded", "padded"])
+@pytest.mark.parametrize("mode", ["pd", "d"])
+def test_traced_grid_solve_is_bitwise_identical_with_counters(mode, pad):
+    ref, res, tr = _grid_solve(pad, mode)
+    _leaves_bit_eq(res, ref)
+    # the counters repeat exactly: a second traced solve gives the same
+    # trace, new leaves included
+    cfg = GRID_CFG
+    _, tr2 = api.solve(_grid(pad), mode=mode, config=cfg, trace=True)
+    _leaves_bit_eq(tr2, tr)
+    assert _live(tr, "long_rows").sum() > 0
+
+
+def _np_components(u, v, mask, n):
+    """NumPy replay of connected_components: (labels, loop trips)."""
+    labels, trips = np.arange(n), 0
+    while True:
+        trips += 1
+        lu, lv = labels[u], labels[v]
+        m = np.minimum(lu, lv)
+        new = labels.copy()
+        np.minimum.at(new, u, np.where(mask, m, lu))
+        np.minimum.at(new, v, np.where(mask, m, lv))
+        new = new[new]
+        new = new[new]
+        if np.array_equal(new, labels):
+            return new, trips
+        labels = new
+
+
+def _np_forest(u, v, cost, ev, n, rounds):
+    """NumPy replay of spanning_forest_contraction: (S, summed trips)."""
+    E = len(u)
+    neg = ev & (cost < 0)
+    S, labels, trips = np.zeros(E, bool), np.arange(n), 0
+    for _ in range(rounds):
+        cl_u, cl_v = labels[u], labels[v]
+        active = ev & (cost > 0) & (cl_u != cl_v)
+        best = {}          # component -> concat index of its best edge
+        for k, (seg, e) in enumerate(zip(np.concatenate([cl_u, cl_v]),
+                                         np.tile(np.arange(E), 2))):
+            if active[e] and (seg not in best
+                              or cost[e] > cost[best[seg] % E]):
+                best[seg] = k
+        cand = np.zeros(E, bool)
+        cand[[k % E for k in best.values()]] = True
+        lt, t1 = _np_components(u, v, S | cand, n)
+        conflict = neg & (lt[u] == lt[v]) & (labels[u] != labels[v])
+        frozen = np.zeros(n, bool)
+        frozen[lt[u][conflict]] = True
+        S = S | (cand & ~frozen[lt[u]] & ~frozen[lt[v]])
+        labels, t2 = _np_components(u, v, S, n)
+        trips += t1 + t2
+    return S, trips
+
+
+@pytest.mark.parametrize("density", [0.0, 0.3, 0.7, 1.0])
+def test_components_trips_match_numpy_replay(density):
+    inst = grid_instance(16, 16, seed=2)
+    mask = np.random.default_rng(3).random(inst.num_edges) < density
+    mask &= np.asarray(inst.edge_valid)
+    labels, trips = jax.jit(
+        lambda m: connected_components(inst.u, inst.v, m, inst.num_nodes,
+                                       with_aux=True))(mask)
+    want_labels, want_trips = _np_components(
+        np.asarray(inst.u), np.asarray(inst.v), mask, inst.num_nodes)
+    assert np.array_equal(np.asarray(labels), want_labels)
+    assert int(trips) == want_trips
+
+
+def test_round_counters_match_numpy_replay():
+    """Round 0 of a p solve contracts the original instance: its
+    ``cc_iters`` is the forest's 8 loops plus the contraction's one,
+    replayed in NumPy, and ``forest_chosen`` the matching/forest switch
+    recomputed from the two candidate sets."""
+    inst, cfg = _grid(1024), GRID_CFG
+    _, _, tr = _grid_solve(1024, "p")
+    u, v = np.asarray(inst.u), np.asarray(inst.v)
+    cost, ev = np.asarray(inst.cost), np.asarray(inst.edge_valid)
+    S_forest, forest_trips = _np_forest(u, v, cost, ev, inst.num_nodes,
+                                        cfg.forest_rounds)
+    assert np.array_equal(
+        S_forest, np.asarray(spanning_forest_contraction(
+            inst, rounds=cfg.forest_rounds)))
+    S_match = np.asarray(maximum_matching(inst,
+                                          rounds=cfg.matching_rounds))
+    n_nodes = int(np.sum(np.asarray(inst.node_valid)))
+    use_match = (S_match.sum() >= cfg.switch_frac * n_nodes) \
+        | (S_forest.sum() < S_match.sum())
+    S = S_match if use_match else S_forest
+    _, contract_trips = _np_components(u, v, S & ev, inst.num_nodes)
+    assert int(tr.cc_iters[0]) == forest_trips + contract_trips
+    assert int(tr.forest_chosen[0]) == int(not use_match)
+    rows = _live(tr, "forest_chosen")
+    assert set(rows.tolist()) <= {0, 1}
+    assert np.all(_live(tr, "cc_iters") >= 2 * cfg.forest_rounds + 1)
+
+
+@pytest.mark.parametrize("pad", [None, 1024], ids=["unpadded", "padded"])
+def test_chord_counters(pad):
+    """With no free edge slot every fresh chord is dropped and no 4- or
+    5-cycle is separated; with 487 free slots none is, and the chords
+    added are the growth of the valid-edge count."""
+    inst = _grid(pad)
+    _, _, tr = _grid_solve(pad, "d")
+    added, dropped = _live(tr, "chords_added"), _live(tr, "chords_dropped")
+    sep = jax.jit(lambda i: separate(
+        i, max_neg=GRID_CFG.max_neg,
+        max_tri_per_edge=GRID_CFG.max_tri_per_edge, nbr_k=GRID_CFG.nbr_k,
+        graph_impl="sparse", sparse_row_cap=GRID_CFG.sparse_row_cap,
+        sparse_row_cap_short=GRID_CFG.sparse_row_cap_short,
+        with_aux=True))(inst)
+    grown = int(np.sum(np.asarray(sep.instance.edge_valid))) \
+        - int(np.sum(np.asarray(inst.edge_valid)))
+    assert int(sep.counts.chords_added) == grown == int(added[0])
+    assert int(sep.counts.chords_dropped) == int(dropped[0])
+    if pad is None:
+        assert dropped.sum() > 0 and added.sum() == 0
+    else:
+        assert dropped.sum() == 0 and added.sum() > 0
+        _, final, tr_d = jax.jit(lambda i: _solve_d_device(
+            i, GRID_CFG, trace=True))(inst)
+        assert int(np.sum(np.asarray(final.edge_valid))) \
+            - int(np.sum(np.asarray(inst.edge_valid))) == added.sum()
+        _leaves_bit_eq(tr_d, tr)
+
+
+@pytest.mark.parametrize("mode,impl,padding", [
+    ("p", "sparse", SEP_COUNTERS),
+    ("d", "sparse", CON_COUNTERS),
+    ("pd", "dense", ("long_rows",)),
+])
+def test_counters_are_padding_where_not_run(mode, impl, padding):
+    _, _, tr = _grid_solve(1024, mode, impl)
+    for name in padding:
+        assert not np.any(np.asarray(getattr(tr, name))), name
+    ran = set(SEP_COUNTERS + CON_COUNTERS) - set(padding) \
+        - {"chords_dropped"}
+    for name in ran:
+        assert _live(tr, name).sum() > 0, name
+
+
+def test_summarize_reports_counter_totals():
+    _, _, tr = _grid_solve(1024, "pd")
+    s = summarize(tr)
+    for name in SEP_COUNTERS + CON_COUNTERS:
+        assert s[f"total_{name}"] == int(_live(tr, name).sum())
+        assert s["per_round"][0][name] == int(getattr(tr, name)[0])
