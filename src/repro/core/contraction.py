@@ -168,7 +168,9 @@ def choose_contraction_set(inst: MulticutInstance, matching_rounds: int = 3,
                            with_aux: bool = False):
     """Paper §3.1: matching first; if it matched fewer than
     ``switch_frac * |V|`` edges, use the spanning-forest strategy instead.
-    Both branches are computed (fixed-shape) and selected with ``where``.
+    The forest runs under a ``lax.cond`` on that test, so a round whose
+    matching suffices never computes it (under ``vmap`` the predicate is
+    batched and the cond becomes a select: both branches run there).
 
     ``contract_frac`` > 0 restricts candidates to edges with cost above that
     fraction of the round's maximum positive cost — a GAEC-like conservatism
@@ -180,11 +182,12 @@ def choose_contraction_set(inst: MulticutInstance, matching_rounds: int = 3,
     empty set would terminate the outer solver while positive edges remain.
     We therefore never return fewer edges than the matching found.
 
-    The two branches run under the named scopes
+    The two strategies run under the named scopes
     ``repro.contraction.matching`` and ``repro.contraction.forest``.
-    ``with_aux`` (static) returns ``(S, cc_iters, forest_chosen)``: the
-    forest's summed connected-components trips and whether the forest's
-    set was kept (i32 scalars)."""
+    ``with_aux`` (static) returns ``(S, cc_iters, forest_chosen,
+    forest_ran)``: the forest's summed connected-components trips (0
+    where it did not run), whether the forest's set was kept, and
+    whether the forest ran (i32 scalars)."""
     min_cost = 0.0
     if contract_frac > 0.0:
         cmax = jnp.max(jnp.where(inst.edge_valid, inst.cost, 0.0))
@@ -194,16 +197,27 @@ def choose_contraction_set(inst: MulticutInstance, matching_rounds: int = 3,
                                    min_cost=min_cost)
     n_nodes = jnp.sum(inst.node_valid)
     enough = jnp.sum(S_match) >= switch_frac * n_nodes
-    with jax.named_scope("repro.contraction.forest"):
-        forest = spanning_forest_contraction(inst, rounds=forest_rounds,
-                                             min_cost=min_cost,
-                                             with_aux=with_aux)
-    S_forest = forest[0] if with_aux else forest
-    use_match = enough | (jnp.sum(S_forest) < jnp.sum(S_match))
-    S = jnp.where(use_match, S_match, S_forest)
+    zero = jnp.int32(0)
+
+    def matching_only():
+        return (S_match, zero, zero) if with_aux else S_match
+
+    def with_forest():
+        with jax.named_scope("repro.contraction.forest"):
+            forest = spanning_forest_contraction(
+                inst, rounds=forest_rounds, min_cost=min_cost,
+                with_aux=with_aux)
+        S_forest = forest[0] if with_aux else forest
+        use_match = jnp.sum(S_forest) < jnp.sum(S_match)
+        S = jnp.where(use_match, S_match, S_forest)
+        if not with_aux:
+            return S
+        return S, forest[1], (~use_match).astype(jnp.int32)
+
+    chosen = jax.lax.cond(enough, matching_only, with_forest)
     if not with_aux:
-        return S
-    return S, forest[1], (~use_match).astype(jnp.int32)
+        return chosen
+    return chosen + ((~enough).astype(jnp.int32),)
 
 
 # ---------------------------------------------------------------------------

@@ -244,8 +244,8 @@ def _primal_round_core(inst: MulticutInstance, cfg: SolverConfig,
                        with_aux: bool = False):
     """Choose the contraction set and contract it. Returns
     (ContractionResult, CsrGraph) — plus, when ``with_aux``, a telemetry
-    dict: ``cc_iters`` (trips of every connected-components loop of the
-    round) and ``forest_chosen``."""
+    dict: ``cc_iters`` (trips of every connected-components loop the
+    round ran), ``forest_chosen`` and ``forest_ran``."""
     with jax.named_scope("repro.contraction"):
         chosen = choose_contraction_set(
             inst, matching_rounds=cfg.matching_rounds,
@@ -253,10 +253,10 @@ def _primal_round_core(inst: MulticutInstance, cfg: SolverConfig,
             contract_frac=cfg.contract_frac, with_aux=with_aux)
         if not with_aux:
             return contract_csr(inst, chosen)
-        S, forest_iters, forest_chosen = chosen
+        S, forest_iters, forest_chosen, forest_ran = chosen
         res, csr, cc_iters = contract_csr(inst, S, with_aux=True)
     return res, csr, dict(cc_iters=forest_iters + cc_iters,
-                          forest_chosen=forest_chosen)
+                          forest_chosen=forest_chosen, forest_ran=forest_ran)
 
 
 def _live_edges1(inst: MulticutInstance) -> jnp.ndarray:

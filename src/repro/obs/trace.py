@@ -26,7 +26,7 @@ unsharded paths so a trace always has the same treedef regardless of
 which solve path produced it. Likewise every path carries every leaf: a
 path that does not run the code a leaf counts leaves it as padding (0) —
 the separation counters in mode p, the contraction counters in mode d,
-``long_rows`` on the dense data path, and all five counters on the
+``long_rows`` on the dense data path, and all six counters on the
 state-sharded solve.
 
 Named scopes: the solver's phases run under ``repro.<phase>``
@@ -51,8 +51,8 @@ __all__ = ["SolveTrace", "init_trace", "trace_set_round", "summarize"]
 
 # Per-round i32 counters of the contraction and separation parts, padded
 # with 0; summarize() reports each one's total over the solve's rounds.
-COUNTERS = ("cc_iters", "forest_chosen", "chords_added", "chords_dropped",
-            "long_rows")
+COUNTERS = ("cc_iters", "forest_chosen", "forest_ran", "chords_added",
+            "chords_dropped", "long_rows")
 
 # Padding sentinels (masked out by `summarize` via `rounds`): +inf for
 # minimised quantities keeps best-so-far scans monotone; -inf for the LB.
@@ -75,10 +75,14 @@ class SolveTrace(NamedTuple):
     - ``mp_improvement``: (R,) f32 — LB gain of the MP sweep over the
       trivial bound Σ min(0, cost) on the round's reparametrized costs.
     - ``cc_iters``: (R,) i32 — while-loop trips of every
-      connected-components call in the round's contraction: the
-      forest's ``2 * forest_rounds`` calls plus the contraction's one.
+      connected-components call the round's contraction ran: the
+      contraction's one, plus the forest's ``2 * forest_rounds`` in a
+      round that ran the forest.
     - ``forest_chosen``: (R,) i32 — 1 where the round contracted the
       spanning forest's set rather than the matching's.
+    - ``forest_ran``: (R,) i32 — 1 where the round computed the spanning
+      forest: the matching fell short of ``switch_frac`` of the live
+      nodes.
     - ``chords_added``: (R,) i32 — fresh 4/5-cycle chords written into
       a free edge slot.
     - ``chords_dropped``: (R,) i32 — fresh chords that found no free
@@ -103,6 +107,7 @@ class SolveTrace(NamedTuple):
     mp_improvement: jnp.ndarray
     cc_iters: jnp.ndarray
     forest_chosen: jnp.ndarray
+    forest_ran: jnp.ndarray
     chords_added: jnp.ndarray
     chords_dropped: jnp.ndarray
     long_rows: jnp.ndarray
@@ -127,6 +132,7 @@ def init_trace(max_rounds: int, shards: int = 1) -> SolveTrace:
         mp_improvement=jnp.zeros((r,), f),
         cc_iters=jnp.zeros((r,), i),
         forest_chosen=jnp.zeros((r,), i),
+        forest_ran=jnp.zeros((r,), i),
         chords_added=jnp.zeros((r,), i),
         chords_dropped=jnp.zeros((r,), i),
         long_rows=jnp.zeros((r,), i),
@@ -139,7 +145,7 @@ def init_trace(max_rounds: int, shards: int = 1) -> SolveTrace:
 def trace_set_round(trace: SolveTrace, r, *, lower_bound=None,
                     objective=None, n_cycles=None, n_contracted=None,
                     n_clusters=None, mp_improvement=None, cc_iters=None,
-                    forest_chosen=None, chords_added=None,
+                    forest_chosen=None, forest_ran=None, chords_added=None,
                     chords_dropped=None, long_rows=None, shard_edges=None,
                     shard_topk=None, shard_halo=None) -> SolveTrace:
     """Write row ``r`` (a traced i32 scalar) of the per-round leaves and
@@ -150,6 +156,7 @@ def trace_set_round(trace: SolveTrace, r, *, lower_bound=None,
                    n_cycles=n_cycles, n_contracted=n_contracted,
                    n_clusters=n_clusters, mp_improvement=mp_improvement,
                    cc_iters=cc_iters, forest_chosen=forest_chosen,
+                   forest_ran=forest_ran,
                    chords_added=chords_added, chords_dropped=chords_dropped,
                    long_rows=long_rows, shard_edges=shard_edges, shard_topk=shard_topk,
                    shard_halo=shard_halo)
@@ -203,10 +210,10 @@ def summarize(trace: SolveTrace) -> dict:
     """Host-side digest of a trace: per-round rows, convergence
     trajectory (first/best/final LB + objective, duality gap), the
     per-solve totals of the contraction and separation counters
-    (``total_cc_iters``, ``total_forest_chosen``, ``total_chords_added``,
-    ``total_chords_dropped``, ``total_long_rows``), and —
-    for sharded solves — per-round imbalance ratios for edges / top-k
-    anchors / halo pressure. This is the ONLY trace consumer that pulls
+    (``total_cc_iters``, ``total_forest_chosen``, ``total_forest_ran``,
+    ``total_chords_added``, ``total_chords_dropped``, ``total_long_rows``),
+    and — for sharded solves — per-round imbalance ratios for edges /
+    top-k anchors / halo pressure. This is the ONLY trace consumer that pulls
     device values to the host; call it off the hot path."""
     rows = _rows(trace)
     out = {"rounds": len(rows), "per_round": rows}

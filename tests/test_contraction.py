@@ -3,11 +3,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core import solver
 from repro.core.contraction import (
     adjacency_dense, choose_contraction_set, connected_components, contract,
     contract_dense, maximum_matching, spanning_forest_contraction,
 )
-from repro.core.graph import make_instance, random_instance, to_host_edges
+from repro.core.graph import (
+    grid_instance, make_instance, random_instance, to_host_edges,
+)
 
 
 def _nx_components(u, v, n):
@@ -136,3 +139,100 @@ def test_choose_contraction_never_empty_while_positive():
         pytest.skip("instance has no positive edges")
     S = choose_contraction_set(inst)
     assert int(jnp.sum(S)) >= 1
+
+
+# ---------------------------------------------------------------------------
+# the forest runs only where the matching falls short
+# ---------------------------------------------------------------------------
+
+def _select_both(inst, matching_rounds=3, forest_rounds=4, switch_frac=0.1,
+                 contract_frac=0.0, with_aux=False):
+    """The chooser as it was before the forest went under a ``lax.cond``:
+    both candidate sets computed, one kept with ``where``."""
+    min_cost = 0.0
+    if contract_frac > 0.0:
+        cmax = jnp.max(jnp.where(inst.edge_valid, inst.cost, 0.0))
+        min_cost = contract_frac * jnp.maximum(cmax, 0.0)
+    S_match = maximum_matching(inst, rounds=matching_rounds,
+                               min_cost=min_cost)
+    enough = jnp.sum(S_match) >= switch_frac * jnp.sum(inst.node_valid)
+    S_forest = spanning_forest_contraction(inst, rounds=forest_rounds,
+                                           min_cost=min_cost)
+    use_match = enough | (jnp.sum(S_forest) < jnp.sum(S_match))
+    S = jnp.where(use_match, S_match, S_forest)
+    if not with_aux:
+        return S
+    return S, (~use_match).astype(jnp.int32), (~enough).astype(jnp.int32)
+
+
+def _chooser_case(case):
+    """An instance for each way the chooser can go."""
+    if case == "matching_suffices":
+        return random_instance(12, 0.3, seed=0, pad_edges=128, pad_nodes=16)
+    if case == "forest_kept":
+        # the matching takes 1 of 12 nodes' edges: short of 10 %
+        return random_instance(12, 0.1, seed=14, pad_edges=128, pad_nodes=16)
+    # a path a-b-c with a repulsive chord a-c among 17 isolated nodes: the
+    # matching takes a-b; the forest's one Borůvka round would close the
+    # chord inside a component, freezes it, and keeps nothing
+    return make_instance([0, 1, 0], [1, 2, 2], [3.0, 2.0, -10.0], 20,
+                         pad_edges=8)
+
+
+@pytest.mark.parametrize("case", ["matching_suffices", "forest_kept",
+                                  "forest_loses"])
+def test_chooser_matches_both_branch_select(case):
+    inst = _chooser_case(case)
+    S, trips, chosen, ran = jax.jit(
+        lambda i: choose_contraction_set(i, with_aux=True))(inst)
+    S_old, chosen_old, ran_old = _select_both(inst, with_aux=True)
+    assert np.array_equal(np.asarray(S), np.asarray(S_old))
+    assert int(chosen) == int(chosen_old)
+    assert int(ran) == int(ran_old)
+    assert np.array_equal(np.asarray(jax.jit(choose_contraction_set)(inst)),
+                          np.asarray(S_old))
+    S_match = np.asarray(maximum_matching(inst))
+    S_forest = np.asarray(spanning_forest_contraction(inst))
+    # the case occurs as named
+    assert int(ran) == (case != "matching_suffices")
+    assert int(chosen) == (case == "forest_kept")
+    if case == "forest_loses":
+        assert S_forest.sum() < S_match.sum()
+    # trips count only loops that ran
+    assert (int(trips) > 0) == bool(ran)
+
+
+@pytest.mark.parametrize("mode", ["p", "pd"])
+def test_solve_matches_both_branch_select(mode, monkeypatch):
+    """A jitted solve of a padded grid, whose rounds take both sides of
+    the matching test, is bitwise the solve under the old select."""
+    inst = grid_instance(12, 12, seed=1, pad_edges=1024)
+    cfg = solver.SolverConfig(graph_impl="sparse")
+    res, tr = jax.jit(lambda i: solver.solve_device(
+        i, mode, cfg, trace=True))(inst)
+    ran = np.asarray(tr.forest_ran)[:int(tr.rounds)]
+    assert 0 < ran.sum() < len(ran)
+    new = jax.jit(lambda i: solver.solve_device(i, mode, cfg))(inst)
+    monkeypatch.setattr(solver, "choose_contraction_set", _select_both)
+    old = jax.jit(lambda i: solver.solve_device(i, mode, cfg))(inst)
+    for x, y, z in zip(jax.tree_util.tree_leaves(new),
+                       jax.tree_util.tree_leaves(old),
+                       jax.tree_util.tree_leaves(res)):
+        assert np.asarray(x).tobytes() == np.asarray(y).tobytes()
+        assert np.asarray(x).tobytes() == np.asarray(z).tobytes()
+
+
+def test_solve_batch_matches_both_branch_select(monkeypatch):
+    """Under ``vmap`` the cond's predicate is batched and both branches
+    run: ``api.solve_batch`` keeps the old select's answers bit for bit."""
+    from repro import api
+    insts = [grid_instance(12, 12, seed=s, pad_edges=1024) for s in (1, 2)]
+    batch = api.stack_instances(insts)
+    cfg = solver.SolverConfig(graph_impl="sparse")
+    new = api.solve_batch(batch, mode="pd", config=cfg, backend="reference")
+    monkeypatch.setattr(solver, "choose_contraction_set", _select_both)
+    old = jax.jit(jax.vmap(lambda i: solver.solve_device(i, "pd", cfg)))(
+        batch)
+    for x, y in zip(jax.tree_util.tree_leaves(new),
+                    jax.tree_util.tree_leaves(old)):
+        assert np.asarray(x).tobytes() == np.asarray(y).tobytes()

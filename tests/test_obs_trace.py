@@ -227,8 +227,8 @@ def test_traced_sharded_solve_bitwise_across_shard_counts():
             assert R >= 1 and tr.shard_edges.shape[1] == S, S
             # the part counters are not threaded through the sharded
             # solve: padding on every row
-            for name in ("cc_iters", "forest_chosen", "chords_added",
-                         "chords_dropped", "long_rows"):
+            for name in ("cc_iters", "forest_chosen", "forest_ran",
+                         "chords_added", "chords_dropped", "long_rows"):
                 assert not np.any(np.asarray(getattr(tr, name))), name
             totals[S] = np.asarray(tr.shard_edges)[:R].sum(axis=1)
             s = summarize(tr)
@@ -255,7 +255,7 @@ def test_traced_sharded_solve_bitwise_across_shard_counts():
 GRID_CFG = SolverConfig(graph_impl="sparse", sparse_row_cap=16,
                         sparse_row_cap_short=4)
 SEP_COUNTERS = ("chords_added", "chords_dropped", "long_rows")
-CON_COUNTERS = ("cc_iters", "forest_chosen")
+CON_COUNTERS = ("cc_iters", "forest_chosen", "forest_ran")
 
 
 def _grid(pad):
@@ -346,13 +346,9 @@ def test_components_trips_match_numpy_replay(density):
     assert int(trips) == want_trips
 
 
-def test_round_counters_match_numpy_replay():
-    """Round 0 of a p solve contracts the original instance: its
-    ``cc_iters`` is the forest's 8 loops plus the contraction's one,
-    replayed in NumPy, and ``forest_chosen`` the matching/forest switch
-    recomputed from the two candidate sets."""
-    inst, cfg = _grid(1024), GRID_CFG
-    _, _, tr = _grid_solve(1024, "p")
+def _check_round0(tr, inst, cfg):
+    """Replay round 0's contraction counters in NumPy; returns whether the
+    matching sufficed (``enough``)."""
     u, v = np.asarray(inst.u), np.asarray(inst.v)
     cost, ev = np.asarray(inst.cost), np.asarray(inst.edge_valid)
     S_forest, forest_trips = _np_forest(u, v, cost, ev, inst.num_nodes,
@@ -363,15 +359,46 @@ def test_round_counters_match_numpy_replay():
     S_match = np.asarray(maximum_matching(inst,
                                           rounds=cfg.matching_rounds))
     n_nodes = int(np.sum(np.asarray(inst.node_valid)))
-    use_match = (S_match.sum() >= cfg.switch_frac * n_nodes) \
-        | (S_forest.sum() < S_match.sum())
+    enough = S_match.sum() >= cfg.switch_frac * n_nodes
+    use_match = enough | (S_forest.sum() < S_match.sum())
     S = S_match if use_match else S_forest
     _, contract_trips = _np_components(u, v, S & ev, inst.num_nodes)
-    assert int(tr.cc_iters[0]) == forest_trips + contract_trips
+    assert int(tr.cc_iters[0]) == \
+        (0 if enough else forest_trips) + contract_trips
     assert int(tr.forest_chosen[0]) == int(not use_match)
-    rows = _live(tr, "forest_chosen")
-    assert set(rows.tolist()) <= {0, 1}
-    assert np.all(_live(tr, "cc_iters") >= 2 * cfg.forest_rounds + 1)
+    assert int(tr.forest_ran[0]) == int(not enough)
+    for name in ("forest_chosen", "forest_ran"):
+        assert set(_live(tr, name).tolist()) <= {0, 1}
+    assert np.all(_live(tr, "forest_chosen") <= _live(tr, "forest_ran"))
+    assert np.all(_live(tr, "cc_iters") >= 1)
+    return bool(enough)
+
+
+def test_round_counters_match_numpy_replay():
+    """Round 0 of a p solve contracts the original instance: its
+    ``cc_iters`` is the contraction's components loop, plus the forest's
+    8 loops if the matching fell short, replayed in NumPy; ``forest_ran``
+    is that test and ``forest_chosen`` the matching/forest switch,
+    recomputed from the two candidate sets."""
+    _, _, tr = _grid_solve(1024, "p")
+    _check_round0(tr, _grid(1024), GRID_CFG)
+
+
+@pytest.mark.parametrize("switch_frac,forest_ran", [(0.1, 0), (0.9, 1)],
+                         ids=["matching_suffices", "forest_runs"])
+def test_forest_ran_matches_numpy_replay(switch_frac, forest_ran):
+    """Round 0 of the grid's p solve runs the forest only where the
+    matching falls short of ``switch_frac`` of the nodes: both cases."""
+    inst = _grid(1024)
+    cfg = dataclasses.replace(GRID_CFG, switch_frac=switch_frac)
+    if switch_frac == GRID_CFG.switch_frac:
+        _, _, tr = _grid_solve(1024, "p")
+    else:
+        _, tr = api.solve(inst, mode="p", config=cfg, trace=True)
+    assert _check_round0(tr, inst, cfg) == (not forest_ran)
+    assert int(tr.forest_ran[0]) == forest_ran
+    assert summarize(tr)["total_forest_ran"] == \
+        int(_live(tr, "forest_ran").sum())
 
 
 @pytest.mark.parametrize("pad", [None, 1024], ids=["unpadded", "padded"])
